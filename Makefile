@@ -15,6 +15,9 @@
 # the pytest lane (`make test-audit`), which drives the same matrix plus
 # the injected-violation regression suite.
 PY ?= python
+# Every target here runs on the CPU, where the Pallas kernels must run in
+# the interpreter (repro.kernels.interpret); on a TPU they compile.
+export REPRO_PALLAS_INTERPRET ?= 1
 # extra pytest flags (CI threads --junitxml=... through here)
 PYTEST_FLAGS ?=
 

@@ -109,6 +109,7 @@ import repro.models as models
 from repro.core.schemes import prefill_time
 from repro.hwmodel.attention_costs import mla_prefill_chunk_cost, prefix_hit_savings
 from repro.hwmodel.platforms import PLATFORMS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import _prepare_mla
 from repro.nn import module as nnm
 from repro.runtime import (
@@ -538,6 +539,7 @@ def main():
     )
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.smoke("deepseek-v2-236b")
     params = nnm.init_params(

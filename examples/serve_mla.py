@@ -39,7 +39,8 @@ every chunk:
                          ever written (token-identical, tier-1-gated);
                          'auto' follows --impl ('kernel' -> pallas)
   --impl {ref,kernel}    attention impl for decode AND (via 'auto' above)
-                         prefill; on CPU kernels run interpreted
+                         prefill; on CPU the kernels need the Pallas
+                         interpreter: REPRO_PALLAS_INTERPRET=1
 
 PR 4 lifts the single-host restriction — the same engine serves sharded:
 
@@ -137,7 +138,8 @@ Serving-flags summary (all compose):
   --requests        10        number of requests in the Poisson stream
   --arrival-rate    0.4       mean requests per decode step (Poisson)
   --seed            0         weight init + sampling PRNG + workload seed
-  --platform        tpu_v5e   hwmodel deployment point for auto-dispatch
+  --platform        ''        hwmodel point auto-dispatch prices against
+                              ('' = the attached TPU's own; tpu_v5e on CPU)
   --engine          sync      paged engine: 'sync' | 'async' (overlapped)
   --max-batch       4         decode slots (continuous batching)
   --block-size      8         tokens per pool block
@@ -190,7 +192,8 @@ import numpy as np
 import repro.configs as configs
 import repro.models as models
 from repro.core.schemes import auto_dispatch, step_time
-from repro.hwmodel.platforms import PLATFORMS
+from repro.hwmodel.platforms import PLATFORMS, resolve_platform
+from repro.launch.compile_cache import enable_compile_cache
 from repro.nn import module as nnm
 from repro.runtime import (AsyncPagedMLAEngine, PagedMLAEngine, Request,
                            SamplingParams, blocks_for)
@@ -202,7 +205,9 @@ ap.add_argument("--block-size", type=int, default=8)
 ap.add_argument("--num-blocks", type=int, default=48)
 ap.add_argument("--arrival-rate", type=float, default=0.4,
                 help="mean requests per decode step (Poisson)")
-ap.add_argument("--platform", default="tpu_v5e", choices=sorted(PLATFORMS))
+ap.add_argument("--platform", default="", choices=[""] + sorted(PLATFORMS),
+                help="hwmodel point auto dispatch prices against; '' = the "
+                     "attached TPU's own (by device_kind), tpu_v5e on CPU")
 ap.add_argument("--shared-prefix-len", type=int, default=16)
 ap.add_argument("--no-prefix-cache", action="store_true")
 ap.add_argument("--prefill-chunk", type=int, default=16)
@@ -245,10 +250,11 @@ ap.add_argument("--admission-age-bound", type=int, default=64,
                 help="admit a request unconditionally after cache-aware "
                      "admission bypassed it this many times")
 args = ap.parse_args()
+enable_compile_cache()
 
 cfg = configs.smoke("deepseek-v2-236b")
 mla = cfg.mla_config()
-plat = PLATFORMS[args.platform]
+plat = resolve_platform(args.platform)
 bs = args.block_size
 mesh = None
 if args.mesh:
@@ -326,7 +332,8 @@ dt = time.time() - t0
 
 lat = [r.finished_step - r.arrival for r in engine.sched.finished]
 print(f"\nserved {args.requests} requests in {summary['steps']:.0f} steps / "
-      f"{dt:.2f}s wall ({summary['tokens_per_s']:.1f} decode tok/s on CPU)")
+      f"{dt:.2f}s wall ({summary['tokens_per_s']:.1f} decode tok/s on "
+      f"{jax.devices()[0].platform})")
 print(f"  mid-generation admissions : {summary['mid_gen_admissions']:.0f}"
       f" / {summary['admissions']:.0f}")
 print(f"  preemptions (recompute)   : {summary['preemptions']:.0f}")

@@ -62,6 +62,9 @@ def main(argv=None):
     if args.lint is None:
         args.lint = args.matrix in ("single", "all", "none")
 
+    # the audit budgets are calibrated on CPU, with the Pallas kernels in
+    # the interpreter (repro.kernels.interpret)
+    os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
     if args.matrix in ("mesh", "all"):
         # must land before jax (imported transitively below) initializes
         os.environ["XLA_FLAGS"] = (

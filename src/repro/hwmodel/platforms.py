@@ -38,6 +38,38 @@ PLATFORMS: Dict[str, PlatformPoint] = {
     "h100": PlatformPoint("h100", 989.0e12, 3352.0e9),
 }
 
+# The TPU a process runs on, by ``jax.devices()[0].device_kind``, mapped to
+# its PLATFORMS entry.  Peaks: Google Cloud documentation, "TPU v5e" (197
+# TFLOP/s bf16, 819 GB/s HBM) and "TPU v4" (275 TFLOP/s bf16, 1228 GB/s).
+DEVICE_KINDS: Dict[str, str] = {
+    "TPU v5 lite": "tpu_v5e",
+    "TPU v4": "tpu_v4",
+}
+
+
+def device_platform(device) -> PlatformPoint:
+    """The PlatformPoint of an attached TPU ``device``.  A kind missing from
+    DEVICE_KINDS is an error, never a default."""
+    kind = device.device_kind
+    if kind not in DEVICE_KINDS:
+        raise KeyError(f"no PlatformPoint for device_kind {kind!r}; known: "
+                       f"{sorted(DEVICE_KINDS)} (hwmodel/platforms.py)")
+    return PLATFORMS[DEVICE_KINDS[kind]]
+
+
+def resolve_platform(name: str = "") -> PlatformPoint:
+    """The point ``auto`` dispatch prices against: the PLATFORMS entry
+    ``name`` when given (what-if pricing); else, on a TPU, the chip's own
+    (``device_platform``); else, on a host with no TPU, the deployment
+    target tpu_v5e."""
+    import jax
+    if name:
+        return PLATFORMS[name]
+    if jax.default_backend() == "tpu":
+        return device_platform(jax.devices()[0])
+    return PLATFORMS["tpu_v5e"]
+
+
 # TPU v5e chip + pod constants used by the roofline report (EXPERIMENTS.md).
 TPU_V5E_PEAK_FLOPS = 197.0e12      # bf16
 TPU_V5E_HBM_BW = 819.0e9           # B/s

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .interpret import resolve as resolve_interpret
+
 NEG_INF = -2.0 ** 30
 
 
@@ -195,9 +197,7 @@ def flash_attention(q, k, v, causal: bool = True, window: Optional[int] = None,
 
 def _resolve(softmax_scale, Dqk, interpret):
     scale = softmax_scale if softmax_scale is not None else Dqk ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    return scale, interpret
+    return scale, resolve_interpret(interpret)
 
 
 def _flash_fwd(q, k, v, causal, window, q_offset, softmax_scale, block_q,

@@ -37,6 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .interpret import resolve as resolve_interpret
+
 NEG_INF = -2.0 ** 30
 INV_LN2 = 1.4426950408889634        # log2(e): folds exp into exp2
 RESCALES = ("exp_add", "mul")
@@ -198,8 +200,6 @@ def mla_decode_paged_kernel(q_full, ckv_pages, krope_pages, block_tables,
     bs = ckv_pages.shape[1]
     nb = block_tables.shape[1]
     scale = softmax_scale if softmax_scale is not None else D ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     quantized = ckv_scales is not None
     if quantized != (krope_scales is not None):
         raise ValueError("pass both ckv_scales and krope_scales or neither")
@@ -237,7 +237,7 @@ def mla_decode_paged_kernel(q_full, ckv_pages, krope_pages, block_tables,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, v_dim), q_full.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*operands)
     return out
 
@@ -252,8 +252,6 @@ def mla_decode_kernel(q_full, ckv, krope, index, *,
     B, H, D = q_full.shape
     S, v_dim = ckv.shape[1], ckv.shape[2]
     scale = softmax_scale if softmax_scale is not None else D ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     bk = min(block_k, S)
     pad = -S % bk
     if pad:
@@ -282,6 +280,6 @@ def mla_decode_kernel(q_full, ckv, krope, index, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, v_dim), q_full.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(index, q_full, ckv, krope)
     return out

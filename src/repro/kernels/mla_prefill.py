@@ -17,10 +17,15 @@ prefill phase cannot afford (see hwmodel.attention_costs
 TPU mapping:
   grid (B, nq, nb) — kv-blocks innermost (sequential), query tiles of
   ``block_q`` chunk rows next, batch outermost.  Online-softmax state
-  lives in VMEM scratch shaped (block_q*H, D_kvl): per-instance VMEM at
-  H=128, C=32(bq=16), D=576, bs=128: q 16*128x576x4 = 4.5 MB, pool block
-  128x576x4 = 288 KB, scores 2048x128x4 = 1 MB, acc 2048x512x4 = 4 MB
-  => ~10 MB (tighten block_q for bigger chunks).
+  lives in VMEM scratch shaped (block_q*H, D_kvl).  A tile holds
+  block_q*H score rows, and each row costs ~5 KB of VMEM at D=576 (the
+  double-buffered bf16 q and output blocks, the f32 q and acc, the
+  m/l/score lanes).  The default tile keeps block_q*H <= MAX_TILE_ROWS =
+  2048 (~10 MB), inside the 16 MiB scoped VMEM limit of a v5e: at H=128
+  that is block_q=16, and the whole chunk (block_q=32, 4096 rows, 20 MB)
+  is refused by the compiler.  Each query tile re-streams the prefix
+  blocks it attends, so a chunk of C rows reads them ceil(C/block_q)
+  times.
 
 Ragged semantics (shared with core.cache / runtime.scheduler):
   * ``lengths[b]`` — absolute position of row b's FIRST chunk token
@@ -47,9 +52,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .interpret import resolve as resolve_interpret
 from .mla_decode import softmax_tile_update
 
 NEG_INF = -2.0 ** 30
+MAX_TILE_ROWS = 2048        # block_q * H score rows per tile (see above)
 
 
 def _prefill_kernel(bt_ref, len_ref, nv_ref, q_ref, ckv_ref, krope_ref,
@@ -116,8 +123,9 @@ def mla_prefill_paged_kernel(q_full, ckv_pages, krope_pages, block_tables,
     (N, bs, Dr); block_tables (B, nb) int32; lengths (B,) int32 —
     absolute position of each row's first chunk token; n_valid (B,)
     int32 — real tokens per row (0 = idle slot -> zero output rows).
-    ``block_q``: query-tile rows (0 = whole chunk; C is padded up to a
-    tile multiple, pad rows return zeros).  Returns (B, C, H, Dl).
+    ``block_q``: query-tile rows (0 = as many as MAX_TILE_ROWS allows,
+    at most the whole chunk; C is padded up to a tile multiple, pad rows
+    return zeros).  Returns (B, C, H, Dl).
 
     Block tables, lengths and n_valid all ride the scalar-prefetch
     operand: the BlockSpec index_map dereferences ``block_tables[b, j]``
@@ -135,12 +143,12 @@ def mla_prefill_paged_kernel(q_full, ckv_pages, krope_pages, block_tables,
     bs = ckv_pages.shape[1]
     nb = block_tables.shape[1]
     scale = softmax_scale if softmax_scale is not None else D ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     quantized = ckv_scales is not None
     if quantized != (krope_scales is not None):
         raise ValueError("pass both ckv_scales and krope_scales or neither")
-    bq = C if block_q <= 0 else min(block_q, C)
+    if block_q <= 0:
+        block_q = max(1, MAX_TILE_ROWS // H)
+    bq = min(block_q, C)
     pad = -C % bq
     if pad:
         q_full = jnp.pad(q_full, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -185,6 +193,6 @@ def mla_prefill_paged_kernel(q_full, ckv_pages, krope_pages, block_tables,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, nq * bq, H, v_dim), q_full.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*operands)
     return out[:, :C] if pad else out

@@ -2,18 +2,19 @@
 
 Models call attention through these so the implementation is swappable:
   impl='ref'    pure-jnp dense reference (GSPMD partitions it freely)
-  impl='kernel' Pallas kernel (interpret=True on CPU), wrapped in shard_map
-                when a mesh is active so each device runs the kernel on its
-                local shard (batch over DP axes, heads over 'model').
+  impl='kernel' Pallas kernel (compiled with Mosaic; interpreted only where
+                kernels.interpret says so), wrapped in shard_map when a
+                mesh is active so each device runs the kernel on its local
+                shard (batch over DP axes, heads over 'model').
 """
 from __future__ import annotations
 
 import functools
 from typing import Optional
 
+import jax
 from jax.sharding import Mesh, PartitionSpec as PS
 
-from .. import compat
 from . import ref
 from .flash_attention import flash_attention
 from .mla_decode import mla_decode_kernel, mla_decode_paged_kernel
@@ -35,7 +36,7 @@ def attention(q, k, v, *, impl: str = "ref", causal: bool = True,
     dp = dp_axes if dp_axes is not None else tuple(
         a for a in ("pod", "data") if a in mesh.axis_names)
     qs = PS(dp, "model", None, None)
-    return compat.shard_map(fn, mesh=mesh, in_specs=(qs, qs, qs),
+    return jax.shard_map(fn, mesh=mesh, in_specs=(qs, qs, qs),
                             out_specs=qs, check_vma=False)(q, k, v)
 
 
@@ -60,7 +61,7 @@ def mla_decode_attention(q_full, ckv, krope, index, *, impl: str = "ref",
         return fn(q_full, ckv, krope, index)
     dp = dp_axes if dp_axes is not None else tuple(
         a for a in ("pod", "data") if a in mesh.axis_names)
-    return compat.shard_map(
+    return jax.shard_map(
         lambda q, c, r, i: fn(q, c, r, i), mesh=mesh,
         in_specs=(PS(dp, "model", None), PS(dp, None, None),
                   PS(dp, None, None), PS()),
@@ -118,7 +119,7 @@ def mla_decode_paged_attention(q_full, ckv_pages, krope_pages, block_tables,
         def fn(q, c, r, t, i):
             return mla_decode_paged_kernel(
                 q, c, r, t, i, softmax_scale=softmax_scale, rescale=rescale)
-    return compat.shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=tuple(in_specs),
         out_specs=PS(dp, "model", None), check_vma=False,
     )(*operands)
@@ -173,7 +174,7 @@ def mla_prefill_paged_attention(q_full, ckv_pages, krope_pages, block_tables,
     else:
         def fn(q, c, r, t, ln, nv):
             return kfn(q, c, r, t, ln, nv)
-    return compat.shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=tuple(in_specs),
         out_specs=PS(dp, None, "model", None), check_vma=False,
     )(*operands)

@@ -20,7 +20,7 @@ from typing import Any, Dict, Optional
 
 import jax.numpy as jnp
 
-from repro import compat, configs, models
+from repro import configs, models
 from repro.analysis import hlo as hloa
 from repro.configs.shapes import SHAPES
 from repro.hwmodel.platforms import (TPU_V5E_HBM_BW, TPU_V5E_ICI_BW,
@@ -93,7 +93,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, scheme: str = "rc",
 
 
 def analyze_compiled(lowered, compiled, chips: int) -> Dict[str, Any]:
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     mem = compiled.memory_analysis()
     hc = hloa.analyze(compiled.as_text(), num_partitions=chips)
     terms = three_term(

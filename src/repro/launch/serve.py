@@ -154,9 +154,17 @@ Multi-turn & parallel sampling (PR 10):
   the prefix cache and off with --no-prefix-cache.
 
 Common knobs: --arch picks the model family/config, --smoke shrinks it
-to CI size, --platform names the hwmodel deployment point that
-auto_dispatch prices schemes against, and --seed seeds weight init and
-the sampling PRNG.
+to CI size (and computes in f32; published widths run in bf16),
+--platform names the hwmodel deployment point that auto_dispatch prices
+schemes against (default: the attached TPU's own, looked up by
+device_kind; tpu_v5e on a host without one), and --seed seeds weight
+init and the sampling PRNG.
+
+On CPU the Pallas kernels (--impl kernel, --prefill-impl pallas) run
+only in the interpreter, which REPRO_PALLAS_INTERPRET=1 asks for; on a
+TPU they always compile.  The persistent compilation cache lives where
+JAX_COMPILATION_CACHE_DIR says, else in .jax_cache/ at the repo root
+(launch.compile_cache).
 
 Telemetry (PR 7) — composes with every paged flag:
 
@@ -228,7 +236,8 @@ import numpy as np
 from repro import configs, models
 from repro.core import mla as mlalib
 from repro.core.schemes import auto_dispatch
-from repro.hwmodel.platforms import PLATFORMS
+from repro.hwmodel.platforms import PLATFORMS, resolve_platform
+from repro.launch.compile_cache import enable_compile_cache
 from repro.nn import module as nnm
 from repro.runtime.steps import make_prefill_step, make_serve_step
 
@@ -242,7 +251,10 @@ def main():
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--scheme", default="auto",
                     help="auto | naive | seq | rc | ru")
-    ap.add_argument("--platform", default="tpu_v5e")
+    ap.add_argument("--platform", default="", choices=[""] + sorted(PLATFORMS),
+                    help="hwmodel point auto dispatch prices against; '' = "
+                         "the attached TPU's own (by device_kind), tpu_v5e "
+                         "on a host without one")
     ap.add_argument("--impl", default="ref")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--paged", action="store_true",
@@ -326,9 +338,10 @@ def main():
                          "cache-aware admission bypassed it this many "
                          "times")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.smoke(args.arch) if args.smoke else configs.full(args.arch)
-    dtype = jnp.float32
+    dtype = jnp.float32 if args.smoke else jnp.bfloat16
     params = nnm.init_params(jax.random.PRNGKey(args.seed),
                              models.model_defs(cfg), dtype)
     mesh = _parse_mesh(args.mesh)
@@ -356,11 +369,11 @@ def main():
     scheme = args.scheme
     if scheme == "auto":
         if cfg.attn_kind == "mla":
-            platform = PLATFORMS[args.platform]
+            platform = resolve_platform(args.platform)
             cap = args.prompt_len + args.gen
             scheme = auto_dispatch(cfg.mla_config(), platform, cache_len=cap,
                                    batch=args.batch)
-            print(f"[serve] auto_dispatch({args.platform}, L={cap}, "
+            print(f"[serve] auto_dispatch({platform.name}, L={cap}, "
                   f"B={args.batch}) -> scheme '{scheme}'")
         else:
             scheme = "seq"
@@ -468,7 +481,7 @@ def _serve_paged(args, cfg, params, dtype, mesh=None):
         cfg, params, num_blocks=num_blocks, block_size=bs,
         max_batch=max(args.batch, args.n), max_blocks_per_req=per_req,
         compute_dtype=dtype, impl=args.impl, scheme=args.scheme,
-        platform=PLATFORMS[args.platform],
+        platform=resolve_platform(args.platform),
         enable_prefix_cache=not args.no_prefix_cache,
         prefill_mode="chunked" if args.prefill_chunk else "per_request",
         prefill_impl=args.prefill_impl,

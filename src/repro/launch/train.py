@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from repro import configs, models
 from repro.data import DataConfig, SyntheticLM
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.nn import module as nnm
 from repro.nn import sharding as shd
 from repro.optim import AdamWConfig, adamw_init, cosine
@@ -51,7 +51,7 @@ def main():
         mesh = make_production_mesh(multi_pod=args.mesh == "multi")
     else:
         r, c = map(int, args.mesh.split("x"))
-        mesh = jax.make_mesh((r, c), ("data", "model"))
+        mesh = make_mesh((r, c), ("data", "model"))
 
     dtype = jnp.float32 if mesh is None else jnp.bfloat16
     params = nnm.init_params(jax.random.PRNGKey(args.seed),

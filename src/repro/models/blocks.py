@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import compat
 from ..core import cache as cachelib
 from ..core import mla as mlalib
 from ..core.attention import gqa_attention, gqa_decode
@@ -333,7 +332,7 @@ def _slstm_sharded(params, cfg: ModelConfig, x, ctx: Ctx):
     pspecs = jax.tree.map(lambda _: PS(), params)
     state_specs = {k: PS(dp, None) for k in ("h", "c", "n", "m")} \
         if return_state else {}
-    out, state = compat.shard_map(
+    out, state = jax.shard_map(
         local, mesh=ctx.mesh,
         in_specs=(pspecs, PS(dp, None, None)),
         out_specs=(PS(dp, None, None), state_specs),

@@ -113,9 +113,18 @@ def _embed(params, cfg: ModelConfig, tokens, embeds, dtype):
     return x
 
 
-def _logits(params, cfg: ModelConfig, x):
+def _logits(params, cfg: ModelConfig, x, out_dtype=None):
     x = nl.rmsnorm(params["ln_f"], x, cfg.norm_eps)
-    return nl.unembed(params["embed"], x)
+    return nl.unembed(params["embed"], x, out_dtype)
+
+
+def _serve_logits(params, cfg: ModelConfig, x):
+    """Logits a token is sampled from, always f32.  Rounded to bf16, the
+    top two of a 100k vocabulary often tie, and XLA may skip that rounding
+    where it fuses the argmax into the step (the async engine) but not
+    where the logits leave it (the sync engine): the two would then pick
+    different tokens."""
+    return _logits(params, cfg, x, jnp.float32)
 
 
 # ------------------------------------------------------------ public API ---
@@ -151,7 +160,7 @@ def prefill(params, cfg: ModelConfig, tokens, *, embeds=None, capacity: int = 0,
     ctx = Ctx(mode="prefill", positions=positions, impl=impl, mesh=mesh,
               scheme=scheme, capacity=capacity or L, shard_mode=shard_mode)
     x, caches, _ = _run_stack(params, cfg, x, ctx, None)
-    return _logits(params, cfg, x[:, -1]), caches
+    return _serve_logits(params, cfg, x[:, -1]), caches
 
 
 def prefill_chunk_paged(params, cfg: ModelConfig, tokens, pool,
@@ -186,7 +195,7 @@ def prefill_chunk_paged(params, cfg: ModelConfig, tokens, pool,
     B = x.shape[0]
     last = jnp.maximum(jnp.asarray(n_valid, jnp.int32) - 1, 0)
     h = x[jnp.arange(B), last]                    # (B, D) last valid hidden
-    return _logits(params, cfg, h), caches
+    return _serve_logits(params, cfg, h), caches
 
 
 def verify_chunk_paged(params, cfg: ModelConfig, tokens, pool,
@@ -214,7 +223,7 @@ def verify_chunk_paged(params, cfg: ModelConfig, tokens, pool,
                                     compute_dtype=compute_dtype, impl=impl,
                                     mesh=mesh, scheme=scheme,
                                     shard_mode=shard_mode)
-    return _logits(params, cfg, x), caches
+    return _serve_logits(params, cfg, x), caches
 
 
 def _chunk_paged_hidden(params, cfg: ModelConfig, tokens, pool,
@@ -245,7 +254,7 @@ def decode_step(params, cfg: ModelConfig, token, cache, index, *,
               mesh=mesh, scheme=scheme, shard_mode=shard_mode,
               block_tables=block_tables, lengths=lengths)
     x, caches, _ = _run_stack(params, cfg, x, ctx, cache)
-    return _logits(params, cfg, x), caches
+    return _serve_logits(params, cfg, x), caches
 
 
 def init_cache(cfg: ModelConfig, batch: int, capacity: int,

@@ -21,7 +21,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .. import compat
 from ..nn import layers as nl
 from ..nn.module import P
 from .common import ModelConfig
@@ -125,7 +124,7 @@ def moe_apply(params, cfg: ModelConfig, x, *, mesh=None,
                 cfg.moe_d_ff % sizes.get("data", 1) == 0 else None
             fn = lambda xl, rw, gu, dn: _moe_local(
                 xl, rw, gu, dn, cfg=cfg, model_axis="model", f_axis=f_ax)
-            out, aux = compat.shard_map(
+            out, aux = jax.shard_map(
                 fn, mesh=mesh,
                 in_specs=(PS(None, None), PS(None, None),
                           PS("model", None, None, f_ax),
@@ -146,7 +145,7 @@ def moe_apply(params, cfg: ModelConfig, x, *, mesh=None,
                                                model_axis="model")
         # tokens sharded over DP (flattened B*L), replicated over model;
         # experts sharded over model; router replicated.
-        out, aux = compat.shard_map(
+        out, aux = jax.shard_map(
             fn, mesh=mesh,
             in_specs=(PS(dp or None, None), PS(None, None),
                       PS("model", None, None, None), PS("model", None, None)),

@@ -73,10 +73,11 @@ def embed(params, ids, compute_dtype=jnp.bfloat16):
     return jnp.take(params["table"], ids, axis=0).astype(compute_dtype)
 
 
-def unembed(params, x):
-    """Logits projection with the (possibly tied) embedding table."""
+def unembed(params, x, out_dtype=None):
+    """Logits projection with the (possibly tied) embedding table;
+    ``out_dtype`` sets the accumulator's output dtype (default x's)."""
     table = params["table"].astype(x.dtype)
-    return x @ table.T
+    return jnp.matmul(x, table.T, preferred_element_type=out_dtype)
 
 
 # ------------------------------------------------------------------ RoPE ---

@@ -53,6 +53,9 @@ def _path_key(key: jax.Array, path: str) -> jax.Array:
 
 
 def _init_one(key: jax.Array, p: P, default_dtype) -> jax.Array:
+    """Draw one leaf directly in its dtype: a bf16 leaf never exists as an
+    f32 temporary (at DeepSeek-V2 widths the stacked expert weights alone
+    would take 4 GiB), and f32 draws are unchanged."""
     dtype = p.dtype or default_dtype
     if p.init == "zeros":
         return jnp.zeros(p.shape, dtype)
@@ -60,16 +63,15 @@ def _init_one(key: jax.Array, p: P, default_dtype) -> jax.Array:
         return jnp.ones(p.shape, dtype)
     if p.init == "normal":
         std = p.scale if p.scale is not None else 0.02
-        return (jax.random.normal(key, p.shape) * std).astype(dtype)
-    if p.init == "embed":
+    elif p.init == "embed":
         std = p.scale if p.scale is not None else 1.0
-        return (jax.random.normal(key, p.shape) * std).astype(dtype)
-    if p.init == "fan_in":
+    elif p.init == "fan_in":
         # fan-in = product of all dims except the last (output) dim.
         fan_in = max(1, int(np.prod(p.shape[:-1])))
         std = p.scale if p.scale is not None else fan_in ** -0.5
-        return (jax.random.normal(key, p.shape) * std).astype(dtype)
-    raise ValueError(f"unknown init {p.init}")
+    else:
+        raise ValueError(f"unknown init {p.init}")
+    return jax.random.normal(key, p.shape, dtype) * jnp.asarray(std, dtype)
 
 
 def is_def(x) -> bool:

@@ -1206,10 +1206,13 @@ class AsyncPagedMLAEngine(PagedMLAEngine):
             entries.append((s, req))
         tr = self.tel.tracer
         t_tr, t_perf = tr.now(), time.perf_counter()
+        # jnp.array copies: the host mutates pending / block_table / lengths
+        # while this step is in flight, and on the CPU backend jnp.asarray
+        # may alias the numpy buffer instead of copying it
         tokens, self.pool = step_fn(
-            self.params, jnp.asarray(self.pending), self.pool,
-            jnp.asarray(self.sched.block_table),
-            jnp.asarray(self.sched.lengths),
+            self.params, jnp.array(self.pending), self.pool,
+            jnp.array(self.sched.block_table),
+            jnp.array(self.sched.lengths),
             jnp.asarray(rids), jnp.asarray(poss))
         self._inflight = _Inflight(
             tokens=tokens, entries=entries, deferred=[], t_disp_tr=t_tr,

@@ -1,4 +1,3 @@
-import contextlib
 import os
 import sys
 
@@ -7,6 +6,10 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 # Tests run on the single real CPU device (the dry-run subprocesses set
 # their own XLA_FLAGS); keep math deterministic.
 os.environ.setdefault("JAX_ENABLE_X64", "0")
+# The kernels compile with Mosaic unless asked otherwise (repro.kernels
+# .interpret): the CPU suite asks for the Pallas interpreter here, and the
+# subprocess drivers inherit the setting through the environment.
+os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
 
 import pytest  # noqa: E402  (path shim must run first)
 
@@ -15,8 +18,8 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "kernel: Pallas kernel oracle-parity tests — execute (not skip) on "
-        "CPU via pl.pallas_call(interpret=True); ci.yml runs them as a "
-        "dedicated step (`make test-kernels`)")
+        "CPU in the Pallas interpreter; ci.yml runs them as a dedicated "
+        "step (`make test-kernels`)")
     config.addinivalue_line(
         "markers",
         "mesh: multi-device sharded-serving parity tests — execute (not "
@@ -35,20 +38,7 @@ def pytest_configure(config):
 
 
 @pytest.fixture
-def interpret_mode():
-    """Force Pallas kernels onto the interpreter so the oracle-parity
-    suites EXECUTE in CPU CI instead of skipping.
-
-    Newer jax exposes ``pltpu.force_tpu_interpret_mode()``; older
-    versions (the baked-in toolchain) do not, but every kernel wrapper in
-    repro.kernels defaults ``interpret=None`` -> True on the CPU backend,
-    so the fixture degrades to a no-op there — asserted by the suites
-    themselves, which pass ``interpret=True`` explicitly at the kernel
-    level and rely on the backend default at the ops/engine level."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        cm = pltpu.force_tpu_interpret_mode()
-    except (ImportError, AttributeError):
-        cm = contextlib.nullcontext()
-    with cm:
-        yield
+def interpret_mode(monkeypatch):
+    """Run the Pallas kernels in the interpreter for this test, whatever
+    the process setting (repro.kernels.interpret)."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")

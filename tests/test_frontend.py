@@ -130,6 +130,9 @@ def test_stop_sequence_over_http(smoke_model, frontend):
     cfg, params = smoke_model
     free = _reference(cfg, params, PROMPT, 8)
     stop = [free[2:4]]
+    # the gram can occur before position 2 (a random model repeats tokens):
+    # generation stops at its first occurrence
+    first = next(i for i in range(len(free)) if free[i:i + 2] == stop[0])
     resp = _post(frontend, "/v1/generate",
                  {"prompt": PROMPT, "max_tokens": 8, "stop": stop,
                   "stream": True})
@@ -139,7 +142,7 @@ def test_stop_sequence_over_http(smoke_model, frontend):
     assert done["finish_reason"] == "stop"
     # the matched stop gram is hidden, and the streamed prefix never
     # leaked a token the truncation later removed (hold-back works)
-    assert done["output"] == free[:2]
+    assert done["output"] == free[:first]
     assert toks == done["output"][:len(toks)]
 
 

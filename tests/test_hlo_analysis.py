@@ -3,7 +3,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro import compat
 from repro.analysis import hlo as hloa
 
 
@@ -26,7 +25,7 @@ def test_scan_flops_exact_vs_xla_undercount():
     assert got == pytest.approx(expected, rel=0.01)
     # and XLA's own cost_analysis undercounts the loop (the reason this
     # module exists) — if XLA ever fixes this, we can drop the parser.
-    xla = compat.cost_analysis(comp).get("flops", 0)
+    xla = comp.cost_analysis().get("flops", 0)
     assert xla < expected
 
 

@@ -114,3 +114,21 @@ def test_mla_decode_kernel_masks_beyond_index():
                               krope.at[:, 20:].set(1e4), 19, block_k=16,
                               interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out_p), atol=1e-6)
+
+
+def test_interpret_mode_is_explicit_and_never_on_a_tpu(monkeypatch):
+    from repro.kernels import interpret
+    monkeypatch.delenv(interpret.ENV, raising=False)
+    assert interpret.resolve() is False          # default: compile (Mosaic)
+    monkeypatch.setenv(interpret.ENV, "0")
+    assert interpret.resolve() is False
+    assert interpret.resolve(True) is True
+    monkeypatch.setenv(interpret.ENV, "1")
+    assert interpret.resolve() is True
+    assert interpret.resolve(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="TPU"):
+        interpret.resolve()
+    with pytest.raises(RuntimeError, match="TPU"):
+        interpret.resolve(True)
+    assert interpret.resolve(False) is False

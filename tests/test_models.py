@@ -105,6 +105,25 @@ def test_prefill_decode_match_forward(arch):
                                rtol=1e-4)
 
 
+def test_serving_logits_are_f32_under_bf16():
+    """Tokens are sampled from f32 logits whatever the compute dtype: in
+    bf16 the top two of a large vocabulary often tie, and a step that
+    fuses the argmax may see them unrounded while one that returns the
+    logits rounds them."""
+    cfg = C.smoke("deepseek-v2-236b")
+    bf = jnp.bfloat16
+    params = nnm.init_params(jax.random.PRNGKey(1), models.model_defs(cfg), bf)
+    toks = _batch(cfg, B=2, L=8, seed=2)["tokens"]
+    last, cache = models.prefill(params, cfg, toks, capacity=16,
+                                 compute_dtype=bf)
+    step, _ = models.decode_step(params, cfg,
+                                 jnp.argmax(last, -1).astype(jnp.int32),
+                                 cache, toks.shape[1], compute_dtype=bf)
+    train, _ = models.forward(params, cfg, toks, compute_dtype=bf)
+    assert last.dtype == step.dtype == jnp.float32
+    assert train.dtype == bf          # the training head is unchanged
+
+
 def test_full_configs_param_counts():
     """FULL configs match the published model sizes (±10%)."""
     expect = {

@@ -406,3 +406,23 @@ def test_auto_dispatch_accepts_paged_block():
     s = auto_dispatch(ac.DSV3_MLA, PLATFORMS["tpu_v5e"], cache_len=4096,
                       batch=8, paged_block=64)
     assert s in ("seq", "rc", "ru")
+
+
+def test_auto_dispatch_prices_the_attached_tpu_by_device_kind(monkeypatch):
+    from types import SimpleNamespace
+    from repro.hwmodel import platforms as plat
+    assert plat.device_platform(
+        SimpleNamespace(device_kind="TPU v5 lite")) is PLATFORMS["tpu_v5e"]
+    with pytest.raises(KeyError, match="TPU v9"):
+        plat.device_platform(SimpleNamespace(device_kind="TPU v9"))
+    # a named point is what-if pricing, on any backend
+    assert plat.resolve_platform("h100") is PLATFORMS["h100"]
+    assert plat.resolve_platform() is PLATFORMS["tpu_v5e"]     # CPU host
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [
+        SimpleNamespace(device_kind="TPU v4")])
+    assert plat.resolve_platform() is PLATFORMS["tpu_v4"]
+    monkeypatch.setattr(jax, "devices", lambda *a: [
+        SimpleNamespace(device_kind="TPU v9")])
+    with pytest.raises(KeyError):
+        plat.resolve_platform()

@@ -377,8 +377,13 @@ from repro.launch.mesh import make_mesh
 from repro.nn import module as nnm
 from repro.runtime import PagedMLAEngine, Request, shallow_draft
 from repro.hwmodel.platforms import PLATFORMS
+import dataclasses
 
-cfg = configs.smoke("deepseek-v2-236b")
+# drop-free routing: MoE capacity drops depend on how many tokens a step
+# carries (k + 1 per row in verify, 1 in decode), which is capacity
+# semantics, not a spec-decode bug
+cfg = dataclasses.replace(configs.smoke("deepseek-v2-236b"),
+                          capacity_factor=64.0)
 params = nnm.init_params(jax.random.PRNGKey(0), models.model_defs(cfg),
                          jnp.float32)
 rng = np.random.default_rng(7)
