@@ -109,7 +109,7 @@ bench-serving:
 # prefill compiles, utilization vs the contiguous baseline, sharded-row
 # token parity + per-device paged-byte scaling, spec-decode parity +
 # acceptance + modeled amortization, telemetry parity + trace validity +
-# roofline-drift coverage + disabled-mode overhead).  Artifacts include
+# disabled-mode overhead).  Artifacts include
 # trace_serving.json / metrics_serving.json / bench_drift.json.
 bench-smoke:
 	PYTHONPATH=src $(PY) benchmarks/bench_serving.py --requests 6 \

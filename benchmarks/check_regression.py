@@ -85,19 +85,7 @@ RULES = [
     # step latency: only a large slowdown fails
     ("bench_prefill_kernel.json", "gather.step_ms", "max_ratio", 4.0),
     ("bench_prefill_kernel.json", "pallas.step_ms", "max_ratio", 4.0),
-    # roofline drift (PR 7): structure is deterministic (row counts per
-    # kind, dispatched-scheme coverage) so it's exact; the measured/
-    # modeled time ratio is CPU wall vs a TPU model, so only its p50 and
-    # p95/p50 spread are held, with wide bands — jit-compile outliers
-    # land in the p95 and CI boxes differ from the baseline machine.
-    ("bench_drift.json", "report.rows", "eq", None),
-    ("bench_drift.json", "report.kinds.decode.schemes", "eq", None),
-    ("bench_drift.json", "report.kinds.decode.rows", "eq", None),
-    ("bench_drift.json", "report.kinds.verify.schemes", "eq", None),
-    ("bench_drift.json", "report.kinds.verify.rows", "eq", None),
-    ("bench_drift.json", "report.kinds.prefill.rows", "eq", None),
-    ("bench_drift.json", "report.summary.time_ratio_p50", "max_ratio", 8.0),
-    ("bench_drift.json", "report.summary.spread", "max_ratio", 10.0),
+    # telemetry run (PR 7): every finished request has a TTFT sample
     ("bench_drift.json", "ttft_ms.count", "eq", None),
     # open-loop load harness (PR 9): step-denominated metrics are
     # deterministic (seeded arrivals, seeded sampling, step-count

@@ -87,9 +87,7 @@ per-element multiply):
                          in tests/test_quant_cache.py.  Requires
                          --prefill-chunk > 0.
 
-PR 7 makes the whole run observable (repro.obs) — spans, metrics, and
-the roofline drift channel that checks the dispatch's own cost model
-against measured step times:
+PR 7 makes the whole run observable (repro.obs) — spans and metrics:
 
   --trace PATH           Chrome/Perfetto trace-event JSON: per-request
                          lifecycle spans (arrival -> queued -> prefill ->
@@ -100,8 +98,7 @@ against measured step times:
   --metrics PATH         metrics-registry JSON (counters, gauges,
                          TTFT/TPOT/queue-delay/step-time histograms with
                          p50/p95/p99 + the engine summary) and a printed
-                         table.  Either flag also records predicted-vs-
-                         measured drift per dispatched scheme.
+                         table.
 
 PR 9 overlaps host and device — ``--engine async`` runs the double-
 buffered AsyncPagedMLAEngine: the fused decode+sample step for tick N is
@@ -301,8 +298,7 @@ if args.spec_k:
 tel = None
 if args.trace or args.metrics:
     from repro.obs import Telemetry
-    tel = Telemetry.on(trace=bool(args.trace), metrics=bool(args.metrics),
-                       drift=True)
+    tel = Telemetry.on(trace=bool(args.trace), metrics=bool(args.metrics))
 engine_cls = AsyncPagedMLAEngine if args.engine == "async" else PagedMLAEngine
 engine = engine_cls(cfg, params, num_blocks=args.num_blocks,
                     block_size=bs, max_batch=args.max_batch,
@@ -374,12 +370,6 @@ if tel is not None:
         print(f"  TTFT ms p50/p95           : {ttft.get('p50', 0):.1f}/"
               f"{ttft.get('p95', 0):.1f}")
         print(tel.metrics.render_table())
-    if tel.drift is not None and tel.drift.rows:
-        d = tel.drift.report()
-        print(f"roofline drift: {d['rows']} rows, time-ratio p50 "
-              f"{d['summary']['time_ratio_p50']:.3g} (CPU wall vs "
-              f"{plat.name} prediction), spread "
-              f"{d['summary']['spread']:.2f}")
 
 # latent-cache footprint vs dense-KV equivalent (the paper's Fig 3 point),
 # at the pool's STORAGE dtype (int8/fp8 pay 1 byte/elem + per-row scales)

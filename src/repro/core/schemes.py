@@ -32,8 +32,8 @@ def cache_width(cfg: MLAConfig, platform: PlatformPoint,
     (None / 'bf16' -> the platform's compute width; 'int8' / 'fp8' -> the
     1-byte payload plus the per-row f32 scale overhead amortized over the
     row, see core.cache.cache_element_bytes).  Every roofline entry point
-    below funnels its cache terms through this so the dispatcher, the
-    drift channel and the bench report price the same pool."""
+    below funnels its cache terms through this so the dispatcher and the
+    bench report price the same pool."""
     from .cache import cache_element_bytes  # local import: no cycle
     return cache_element_bytes(cfg.kv_lora_rank, cfg.qk_rope_dim,
                                dtype_bytes=platform.dtype_bytes,

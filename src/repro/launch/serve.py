@@ -178,13 +178,10 @@ Telemetry (PR 7) — composes with every paged flag:
   --metrics PATH      write the metrics-registry JSON (counters, gauges,
                       TTFT/TPOT/queue-delay/step-time histograms with
                       p50/p95/p99, plus the engine summary verbatim) to
-                      PATH and print the human-readable table.  Either
-                      flag also arms the roofline drift channel: every
-                      step logs hwmodel-predicted vs measured time for
-                      the scheme it dispatched (repro.obs.drift).
-                      Off (the default) costs the hot path nothing
-                      measurable — the no-op tracer short-circuits
-                      before any formatting or allocation.
+                      PATH and print the human-readable table.
+                      Spans are always recorded into the process
+                      recorder (repro.obs, a bounded ring); --trace
+                      exports the part of it this run recorded.
 
 Serving-flags summary (the paged runtime; all compose):
 
@@ -476,7 +473,7 @@ def _serve_paged(args, cfg, params, dtype, mesh=None):
     if args.trace or args.metrics:
         from repro.obs import Telemetry
         tel = Telemetry.on(trace=bool(args.trace),
-                           metrics=bool(args.metrics), drift=True)
+                           metrics=bool(args.metrics))
     engine = engine_cls(
         cfg, params, num_blocks=num_blocks, block_size=bs,
         max_batch=max(args.batch, args.n), max_blocks_per_req=per_req,
@@ -544,10 +541,6 @@ def _serve_paged(args, cfg, params, dtype, mesh=None):
             print(f"[serve] telemetry: {channel} -> {path}")
         if tel.metrics is not None:
             print(tel.metrics.render_table())
-        if tel.drift is not None and tel.drift.rows:
-            d = tel.drift.report()["summary"]
-            print(f"[serve] roofline drift: time-ratio p50 "
-                  f"{d['time_ratio_p50']:.3g}, spread {d['spread']:.2f}")
 
 
 def _prepare_mla(params, cfg, scheme):

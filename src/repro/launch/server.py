@@ -46,6 +46,13 @@ the client; the held tokens flush with ``event: done``.  A client
 disconnect mid-stream (BrokenPipeError on write) cancels the request —
 every fork of it, for a group — so its blocks return to the pool
 instead of decoding to max_tokens.
+
+Spans (repro.obs, the engine's tracer, pid ``PID_FRONTEND``, one tid per
+thread): the worker records ``worker_wait`` (asleep with no work),
+``submit`` (draining submissions into the engine) and ``publish``; a
+streaming handler records ``sse_write`` per token, the write+flush of
+its event, with ``lag_ms`` from the worker's put of that token to the
+flush returning.
 """
 from __future__ import annotations
 
@@ -54,10 +61,12 @@ import json
 import queue
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
+from time import perf_counter
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..obs.trace import PID_FRONTEND
 from ..runtime.sampling import SamplingParams
 from ..runtime.scheduler import Request
 
@@ -71,9 +80,10 @@ class _Stream:
     carries ("token", choice, index, token) items plus one final
     ("done", choices) once EVERY member finished.  ``n == 1`` keeps the
     PR-9 item shapes ("token", token) / ("done", reason, output) —
-    direct queue consumers (tests, embedding users) see no change."""
+    direct queue consumers (tests, embedding users) see no change.
+    ``put_t[c][i]`` is when token ``i`` of choice ``c`` was put."""
 
-    __slots__ = ("rid", "reqs", "n", "q", "emitted", "hold")
+    __slots__ = ("rid", "reqs", "n", "q", "emitted", "hold", "put_t")
 
     def __init__(self, rid: int, req: Request, n: int = 1):
         self.rid = rid
@@ -81,6 +91,7 @@ class _Stream:
         self.n = n
         self.q: "queue.Queue[Tuple]" = queue.Queue()
         self.emitted = [0]
+        self.put_t: List[List[float]] = [[]]
         # stop sequences can complete across ticks; never emit a token
         # that a later match could retro-truncate.
         self.hold = max((len(s) for s in req.stop), default=1) - 1
@@ -92,6 +103,7 @@ class _Stream:
     def attach_children(self, children: List[Request]) -> None:
         self.reqs.extend(children)
         self.emitted.extend(0 for _ in children)
+        self.put_t.extend([] for _ in children)
 
 
 class EngineWorker(threading.Thread):
@@ -106,6 +118,7 @@ class EngineWorker(threading.Thread):
     def __init__(self, engine, *, idle_wait_s: float = 0.05):
         super().__init__(daemon=True, name="engine-worker")
         self.engine = engine
+        self.tracer = engine.tel.tracer
         self._idle_wait_s = idle_wait_s
         self._cv = threading.Condition()
         self._pending: List[Tuple[Request, _Stream]] = []
@@ -150,25 +163,38 @@ class EngineWorker(threading.Thread):
         self.join(timeout=30)
 
     # ------------------------------------------------------ worker loop ----
+    def _no_work(self) -> bool:
+        return (not self._stopping and not self._pending
+                and self.engine.idle and not self.engine._cancels)
+
     def run(self) -> None:
+        tr = self.tracer
+        tid = threading.get_ident()
+        tr.set_process_name(PID_FRONTEND, "frontend")
+        tr.set_thread_name(PID_FRONTEND, tid, "engine worker")
         while True:
             with self._cv:
-                while (not self._stopping and not self._pending
-                       and self.engine.idle and not self.engine._cancels):
-                    self._cv.wait(timeout=self._idle_wait_s)
+                if self._no_work():
+                    with tr.span("worker_wait", PID_FRONTEND, tid):
+                        while self._no_work():
+                            self._cv.wait(timeout=self._idle_wait_s)
                 if self._stopping:
                     return
                 pending, self._pending = self._pending, []
-            for req, st in pending:
-                req.arrival = self.engine.stats.steps
-                self.engine.submit(req)
-                if st.n > 1:
-                    # scheduler.submit materialized the fork children —
-                    # wire them into the group stream for publishing
-                    st.attach_children(req.fork_children)
+            if pending:
+                with tr.span("submit", PID_FRONTEND, tid,
+                             args={"n": len(pending)}):
+                    for req, st in pending:
+                        req.arrival = self.engine.stats.steps
+                        self.engine.submit(req)
+                        if st.n > 1:
+                            # scheduler.submit materialized the fork
+                            # children — wire them into the group stream
+                            st.attach_children(req.fork_children)
             if not self.engine.idle or self.engine._cancels:
                 self.engine.step()
-            self._publish()
+            with tr.span("publish", PID_FRONTEND, tid):
+                self._publish()
 
     def _publish(self) -> None:
         done = []
@@ -178,6 +204,7 @@ class EngineWorker(threading.Thread):
                 safe = len(out) if st.req.done \
                     else max(0, len(out) - st.hold)
                 while st.emitted[0] < safe:
+                    st.put_t[0].append(perf_counter())
                     st.q.put(("token", int(out[st.emitted[0]])))
                     st.emitted[0] += 1
                 if st.req.done:
@@ -189,6 +216,7 @@ class EngineWorker(threading.Thread):
                 out = req.output
                 safe = len(out) if req.done else max(0, len(out) - st.hold)
                 while st.emitted[c] < safe:
+                    st.put_t[c].append(perf_counter())
                     st.q.put(("token", c, st.emitted[c],
                               int(out[st.emitted[c]])))
                     st.emitted[c] += 1
@@ -323,6 +351,7 @@ def _make_handler(worker: EngineWorker):
             except (BrokenPipeError, ConnectionResetError):
                 self._cancel_group(st)
                 return
+            tr, tid = worker.tracer, threading.get_ident()
             i = 0
             while True:
                 item = st.q.get()
@@ -344,11 +373,17 @@ def _make_handler(worker: EngineWorker):
                     else:
                         choice, idx, tok = 0, i, item[1]
                         i += 1
+                    t_write = perf_counter()
                     if has_n:
                         self._event("token", {"token": tok, "index": idx,
                                               "choice": choice})
                     else:
                         self._event("token", {"token": tok, "index": idx})
+                    if tr.enabled:
+                        t_done = perf_counter()
+                        tr.complete("sse_write", PID_FRONTEND, tid, t_write,
+                                    t_done, args={"lag_ms": 1e3 * (
+                                        t_done - st.put_t[choice][idx])})
                 except (BrokenPipeError, ConnectionResetError):
                     # client went away: free every fork's blocks
                     self._cancel_group(st)

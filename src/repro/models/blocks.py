@@ -398,9 +398,12 @@ def sub_apply(params, cfg: ModelConfig, desc: Sub, x, ctx: Ctx):
         if cfg.attn_kind == "mla":
             fn = {"decode": _mla_step,
                   "prefill_chunk": _mla_chunk}.get(ctx.mode, _mla_seq)
+            # named scopes group the device ops by layer in a profile
+            with jax.named_scope("mla_attention"):
+                a, new_cache = fn(params["attn"], cfg, desc, h, ctx)
         else:
             fn = _gqa_step if ctx.mode == "decode" else _gqa_seq
-        a, new_cache = fn(params["attn"], cfg, desc, h, ctx)
+            a, new_cache = fn(params["attn"], cfg, desc, h, ctx)
     elif desc.mixer == "mamba":
         if ctx.mode == "decode":
             a, new_cache = mambalib.mamba_step(params["attn"], cfg, h, ctx.cache)

@@ -56,48 +56,50 @@ def _moe_local(x, router_w, gate_up, down, *, cfg: ModelConfig,
     E_local = gate_up.shape[0]
     C = _capacity(T, cfg)
 
-    logits = (x @ router_w.astype(x.dtype)).astype(jnp.float32)   # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, sel = jax.lax.top_k(probs, k)                      # (T, k)
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+    with jax.named_scope("moe_router"):
+        logits = (x @ router_w.astype(x.dtype)).astype(jnp.float32)   # (T, E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, sel = jax.lax.top_k(probs, k)                      # (T, k)
+        gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
 
-    # ---- aux losses (load balance + z-loss) ---------------------------
-    density = jnp.mean(jax.nn.one_hot(sel, E, dtype=jnp.float32), axis=(0, 1))
-    balance = E * jnp.sum(density * jnp.mean(probs, axis=0)) * k
-    z_loss = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
+        # ---- aux losses (load balance + z-loss) ---------------------------
+        density = jnp.mean(jax.nn.one_hot(sel, E, dtype=jnp.float32), axis=(0, 1))
+        balance = E * jnp.sum(density * jnp.mean(probs, axis=0)) * k
+        z_loss = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
 
-    # ---- sort-based slot assignment ------------------------------------
-    flat_e = sel.reshape(-1)                                      # (T*k,)
-    flat_t = jnp.repeat(jnp.arange(T), k)
-    flat_g = gate_vals.reshape(-1)
-    order = jnp.argsort(flat_e)
-    se, st, sg = flat_e[order], flat_t[order], flat_g[order]
-    seg_start = jnp.searchsorted(se, jnp.arange(E))
-    pos = jnp.arange(T * k) - seg_start[se]
-    keep = pos < C
+        # ---- sort-based slot assignment ------------------------------------
+        flat_e = sel.reshape(-1)                                      # (T*k,)
+        flat_t = jnp.repeat(jnp.arange(T), k)
+        flat_g = gate_vals.reshape(-1)
+        order = jnp.argsort(flat_e)
+        se, st, sg = flat_e[order], flat_t[order], flat_g[order]
+        seg_start = jnp.searchsorted(se, jnp.arange(E))
+        pos = jnp.arange(T * k) - seg_start[se]
+        keep = pos < C
 
-    tok_tbl = jnp.full((E, C), T, jnp.int32)                      # T = pad row
-    tok_tbl = tok_tbl.at[se, pos].set(jnp.where(keep, st, T), mode="drop")
-    gate_tbl = jnp.zeros((E, C), jnp.float32)
-    gate_tbl = gate_tbl.at[se, pos].set(jnp.where(keep, sg, 0.0), mode="drop")
+        tok_tbl = jnp.full((E, C), T, jnp.int32)                      # T = pad row
+        tok_tbl = tok_tbl.at[se, pos].set(jnp.where(keep, st, T), mode="drop")
+        gate_tbl = jnp.zeros((E, C), jnp.float32)
+        gate_tbl = gate_tbl.at[se, pos].set(jnp.where(keep, sg, 0.0), mode="drop")
 
-    # local expert shard of the tables
-    r = jax.lax.axis_index(model_axis) if model_axis else 0
-    tok_loc = jax.lax.dynamic_slice_in_dim(tok_tbl, r * E_local, E_local, 0)
-    gate_loc = jax.lax.dynamic_slice_in_dim(gate_tbl, r * E_local, E_local, 0)
+    with jax.named_scope("moe_routed_experts"):
+        # local expert shard of the tables
+        r = jax.lax.axis_index(model_axis) if model_axis else 0
+        tok_loc = jax.lax.dynamic_slice_in_dim(tok_tbl, r * E_local, E_local, 0)
+        gate_loc = jax.lax.dynamic_slice_in_dim(gate_tbl, r * E_local, E_local, 0)
 
-    x_pad = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)], axis=0)
-    x_e = x_pad[tok_loc]                                          # (El, C, D)
-    h = jnp.einsum("ecd,edgf->ecgf", x_e, gate_up.astype(x.dtype))
-    h = jax.nn.silu(h[:, :, 0]) * h[:, :, 1]                      # (El, C, F)
-    y_e = jnp.einsum("ecf,efd->ecd", h, down.astype(x.dtype))
-    y_e = y_e * gate_loc[..., None].astype(x.dtype)
+        x_pad = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)], axis=0)
+        x_e = x_pad[tok_loc]                                          # (El, C, D)
+        h = jnp.einsum("ecd,edgf->ecgf", x_e, gate_up.astype(x.dtype))
+        h = jax.nn.silu(h[:, :, 0]) * h[:, :, 1]                      # (El, C, F)
+        y_e = jnp.einsum("ecf,efd->ecd", h, down.astype(x.dtype))
+        y_e = y_e * gate_loc[..., None].astype(x.dtype)
 
-    out = jnp.zeros((T + 1, D), x.dtype)
-    out = out.at[tok_loc.reshape(-1)].add(y_e.reshape(-1, D))[:T]
-    axes = tuple(a for a in (model_axis, f_axis) if a)
-    if axes:
-        out = jax.lax.psum(out, axes)
+        out = jnp.zeros((T + 1, D), x.dtype)
+        out = out.at[tok_loc.reshape(-1)].add(y_e.reshape(-1, D))[:T]
+        axes = tuple(a for a in (model_axis, f_axis) if a)
+        if axes:
+            out = jax.lax.psum(out, axes)
     dropped = 1.0 - jnp.mean(keep.astype(jnp.float32))
     aux = {"balance": balance, "z_loss": z_loss, "dropped_frac": dropped}
     return out, aux
@@ -133,7 +135,8 @@ def moe_apply(params, cfg: ModelConfig, x, *, mesh=None,
                 check_vma=False,
             )(x2, params["router"], params["gate_up"], params["down"])
             if cfg.n_shared_experts:
-                out = out + nl.mlp(params["shared"], x2, kind="swiglu")
+                with jax.named_scope("moe_shared_experts"):
+                    out = out + nl.mlp(params["shared"], x2, kind="swiglu")
             return out.reshape(shape), aux
         dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
         dp_size = 1
@@ -157,5 +160,6 @@ def moe_apply(params, cfg: ModelConfig, x, *, mesh=None,
                               params["down"], cfg=cfg, model_axis=None)
 
     if cfg.n_shared_experts:
-        out = out + nl.mlp(params["shared"], x2, kind="swiglu")
+        with jax.named_scope("moe_shared_experts"):
+            out = out + nl.mlp(params["shared"], x2, kind="swiglu")
     return out.reshape(shape), aux

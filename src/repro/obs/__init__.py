@@ -1,24 +1,28 @@
-"""Serving telemetry: span tracer (Perfetto trace-event JSON), metrics
-registry (counters / gauges / percentile histograms), roofline drift
-tracking (hwmodel-predicted vs measured step time), and structured
-logging.  ``Telemetry`` is the facade the runtime takes; everything here
-is import-free of the runtime package so it can be used standalone."""
-from .drift import DriftRow, RooflineDrift, batch_bucket
+"""Serving telemetry: span recorder (bounded ring, Perfetto trace-event
+JSON, mirrored into the JAX profiler while it captures), metrics
+registry (counters / gauges / percentile histograms) and structured
+logging.  ``Telemetry`` is the facade the runtime takes; ``recorder()``
+is the process-wide span recorder; everything here is import-free of
+the runtime package so it can be used standalone."""
 from .logger import StructLogger, as_logger
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry, percentile
 from .telemetry import OFF_TELEMETRY, Telemetry
 from .trace import (
     NULL_TRACER,
     PID_ENGINE,
+    PID_FRONTEND,
+    PID_PROCESS,
     PID_REQUESTS,
+    Event,
     NullTracer,
     Tracer,
+    recorder,
     validate_trace,
 )
 
 __all__ = [
     "Counter",
-    "DriftRow",
+    "Event",
     "Gauge",
     "Histogram",
     "MetricsRegistry",
@@ -26,13 +30,14 @@ __all__ = [
     "NullTracer",
     "OFF_TELEMETRY",
     "PID_ENGINE",
+    "PID_FRONTEND",
+    "PID_PROCESS",
     "PID_REQUESTS",
-    "RooflineDrift",
     "StructLogger",
     "Telemetry",
     "Tracer",
     "as_logger",
-    "batch_bucket",
     "percentile",
+    "recorder",
     "validate_trace",
 ]

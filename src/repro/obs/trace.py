@@ -1,39 +1,74 @@
-"""Low-overhead span tracer emitting Chrome/Perfetto trace-event JSON.
+"""Span recorder emitting Chrome/Perfetto trace-event JSON.
 
 Two implementations behind one duck-typed interface:
 
-  * :class:`Tracer` — records spans ("ph": "X" complete events), instants
-    and metadata rows into an in-memory list and serializes them as the
-    trace-event JSON object format (``{"traceEvents": [...]}``), which
-    loads directly in Perfetto / chrome://tracing via ``export(path)``.
+  * :class:`Tracer` — records spans, instants and retrospective
+    ``complete`` events into a bounded ring (a ``deque`` of
+    :data:`RING_EVENTS`, safe to append to from any thread), serves a time window of them
+    to in-process readers (:meth:`Tracer.window`), and serializes them
+    as the trace-event JSON object format (``{"traceEvents": [...]}``),
+    which loads in Perfetto / chrome://tracing via ``export(path)``.
   * :class:`NullTracer` — the disabled mode.  Every call short-circuits
-    BEFORE any string formatting or dict allocation: ``span()`` returns a
-    module-level singleton context manager and ignores its arguments, so
-    an instrumented hot path costs one attribute lookup plus one call per
-    span when tracing is off (measured < µs/span; bench_serving gates the
-    per-step total under 2% of step latency).
+    BEFORE any string formatting or dict allocation: ``span()`` returns
+    a module-level singleton context manager and ignores its arguments.
 
-Conventions (what the exporter and the tests pin):
+:func:`recorder` is the process-wide :class:`Tracer`: always on, the
+default of every engine and frontend built without explicit telemetry,
+and the one that also records the process's garbage collections and
+XLA compiles.
 
-  * timestamps are MICROseconds since tracer construction
-    (``time.perf_counter`` based — monotonic, sub-µs resolution);
+Conventions (what the exporter, the readers and the tests pin):
+
+  * timestamps are absolute ``time.perf_counter()`` seconds
+    (CLOCK_MONOTONIC, shared by every process on the host);
+    ``to_dict`` rebases them to microseconds at write time;
+  * while a JAX profiler session captures, every live span is also
+    entered as a ``jax.profiler.TraceAnnotation`` of the same name, so
+    it lands on the host plane of the ``.xplane.pb`` on the device ops'
+    clock; its keyword arguments, and the ``detail`` a span may carry,
+    are formatted only then.  Retrospective events stay in the ring;
   * pid :data:`PID_ENGINE` (1) carries the per-step phase spans (tid 0:
-    schedule / prefill / draft / verify / device_step / host_sample,
-    nested under one "step" span per engine tick);
-  * pid :data:`PID_REQUESTS` (2) carries per-request lifecycle spans,
-    one tid per request id (arrival instant, then queued -> prefill ->
-    decode complete spans, then a finish or preempt instant);
+    schedule / prefill / dispatch / draft / verify / device_step /
+    host_sample, nested under one "step" span per engine tick);
+  * pid :data:`PID_REQUESTS` (2) carries per-request lifecycle events,
+    one tid per request id, written when the request finishes;
+  * pid :data:`PID_FRONTEND` (3) carries the HTTP frontend's spans, one
+    tid per thread;
+  * pid :data:`PID_PROCESS` (4) carries ``gc`` (tid 0) and ``compile``
+    (tid 1) events;
   * within one (pid, tid), "X" events are properly nested — no partial
     overlap (:func:`validate_trace` checks this).
 """
 from __future__ import annotations
 
+import collections
+import gc
 import json
+import threading
 from time import perf_counter
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
 
 PID_ENGINE = 1
 PID_REQUESTS = 2
+PID_FRONTEND = 3
+PID_PROCESS = 4
+RING_EVENTS = 2 ** 17
+
+_capturing = TraceAnnotation.is_enabled
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+class Event(NamedTuple):
+    """One recorded event; an instant (``ph == "i"``) has end == start."""
+    name: str
+    ph: str
+    pid: int
+    tid: int
+    start: float
+    end: float
+    args: Optional[Dict]
 
 
 class _NullSpan:
@@ -58,13 +93,16 @@ class NullTracer:
     enabled = False
     __slots__ = ()
 
-    def span(self, name, pid=PID_ENGINE, tid=0):
+    def span(self, name, pid=PID_ENGINE, tid=0, args=None, detail=None):
         return _NULL_SPAN
 
     def complete(self, name, pid, tid, start_s, end_s, args=None):
         pass
 
     def instant(self, name, pid=PID_ENGINE, tid=0, args=None):
+        pass
+
+    def request(self, req):
         pass
 
     def set_process_name(self, pid, name):
@@ -76,10 +114,10 @@ class NullTracer:
     def now(self) -> float:
         return 0.0
 
-    def to_dict(self) -> Dict:
+    def to_dict(self, t0=None, t1=None) -> Dict:
         return {"traceEvents": []}
 
-    def export(self, path: str) -> None:
+    def export(self, path: str, t0=None) -> None:
         raise RuntimeError("cannot export a NullTracer (tracing is off)")
 
 
@@ -87,103 +125,200 @@ NULL_TRACER = NullTracer()
 
 
 class _Span:
-    """Context manager recording one "X" complete event on exit."""
-    __slots__ = ("_tr", "_name", "_pid", "_tid", "_t0", "dur_s")
+    """Context manager recording one "X" event on exit, and mirroring it
+    into a profiler annotation while a capture runs."""
+    __slots__ = ("_tr", "_name", "_pid", "_tid", "_args", "_detail", "_t0",
+                 "_ta", "dur_s")
 
-    def __init__(self, tracer: "Tracer", name: str, pid: int, tid: int):
+    def __init__(self, tracer, name, pid, tid, args, detail):
         self._tr = tracer
         self._name = name
         self._pid = pid
         self._tid = tid
+        self._args = args
+        self._detail = detail
+        self._ta = None
         self.dur_s = 0.0
 
     def __enter__(self):
+        if _capturing():
+            kw = dict(self._args) if self._args else {}
+            if self._detail is not None:
+                kw.update(self._detail())
+            self._ta = TraceAnnotation(self._name, **kw)
+            self._ta.__enter__()
         self._t0 = perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = perf_counter()
+        if self._ta is not None:
+            self._ta.__exit__(*exc)
         self.dur_s = t1 - self._t0
-        tr = self._tr
-        tr.events.append({
-            "name": self._name, "ph": "X", "pid": self._pid,
-            "tid": self._tid, "ts": (self._t0 - tr.t0) * 1e6,
-            "dur": self.dur_s * 1e6,
-        })
+        self._tr._ring.append((self._name, "X", self._pid, self._tid,
+                               self._t0, t1, self._args, t1))
         return False
 
 
 class Tracer:
-    """Recording tracer.  ``now()`` gives seconds since construction on
-    the same clock the spans use, so callers can stamp external
-    timestamps (e.g. request lifecycle times captured by the scheduler)
-    into retrospective :meth:`complete` events."""
+    """Recording tracer over a ring of :data:`RING_EVENTS` events.  A ring
+    record is an :class:`Event`'s fields plus the clock at which it was
+    appended, which tells :meth:`window` whether dropped events could
+    reach into the interval it is asked for."""
 
     enabled = True
 
     def __init__(self):
-        self.t0 = perf_counter()
-        self.events: List[Dict] = []
-        self._named: set = set()
+        self._ring: collections.deque = collections.deque(maxlen=RING_EVENTS)
+        self._names: Dict = {}
+        self._gc_t0 = 0.0
 
     def now(self) -> float:
-        return perf_counter() - self.t0
+        return perf_counter()
 
     # ------------------------------------------------------------ events --
 
-    def span(self, name: str, pid: int = PID_ENGINE, tid: int = 0) -> _Span:
-        return _Span(self, name, pid, tid)
+    def span(self, name: str, pid: int = PID_ENGINE, tid: int = 0,
+             args: Optional[Dict] = None, detail=None) -> _Span:
+        """Live span.  ``args`` go into the ring; ``detail`` is a
+        zero-argument callable returning more keyword arguments, called
+        only while a profiler captures."""
+        return _Span(self, name, pid, tid, args, detail)
 
     def complete(self, name: str, pid: int, tid: int, start_s: float,
                  end_s: float, args: Optional[Dict] = None) -> None:
-        """Retrospective "X" event from two ``now()``-clock timestamps."""
-        ev = {"name": name, "ph": "X", "pid": pid, "tid": tid,
-              "ts": start_s * 1e6, "dur": max(end_s - start_s, 0.0) * 1e6}
-        if args:
-            ev["args"] = args
-        self.events.append(ev)
+        """Retrospective "X" event from two ``perf_counter`` timestamps."""
+        self._ring.append((name, "X", pid, tid, start_s,
+                           max(end_s, start_s), args, perf_counter()))
 
     def instant(self, name: str, pid: int = PID_ENGINE, tid: int = 0,
                 args: Optional[Dict] = None) -> None:
-        ev = {"name": name, "ph": "i", "s": "t", "pid": pid, "tid": tid,
-              "ts": self.now() * 1e6}
-        if args:
-            ev["args"] = args
-        self.events.append(ev)
+        t = perf_counter()
+        self._ring.append((name, "i", pid, tid, t, t, args, t))
 
     def instant_at(self, name: str, pid: int, tid: int, at_s: float,
                    args: Optional[Dict] = None) -> None:
-        ev = {"name": name, "ph": "i", "s": "t", "pid": pid, "tid": tid,
-              "ts": at_s * 1e6}
-        if args:
-            ev["args"] = args
-        self.events.append(ev)
+        self._ring.append((name, "i", pid, tid, at_s, at_s, args,
+                           perf_counter()))
+
+    def request(self, req) -> None:
+        """Lifecycle events of a finished request from the timestamps the
+        scheduler stamped on it (duck-typed: ``rid``, ``submit_t``,
+        ``admit_t``, ``first_tok_t``, ``finish_t``, ``preempt_ts``; -1 is
+        a transition never reached)."""
+        tid = int(req.rid)
+        if req.submit_t >= 0:
+            self.instant_at("arrival", PID_REQUESTS, tid, req.submit_t)
+            if req.admit_t >= 0:
+                self.complete("queued", PID_REQUESTS, tid, req.submit_t,
+                              req.admit_t)
+        if req.admit_t >= 0 and req.first_tok_t >= 0:
+            self.complete("prefill", PID_REQUESTS, tid, req.admit_t,
+                          req.first_tok_t,
+                          args={"plen": req.plen, "cached": req.n_cached})
+        if req.first_tok_t >= 0 and req.finish_t >= 0:
+            self.complete("decode", PID_REQUESTS, tid, req.first_tok_t,
+                          req.finish_t, args={"new_tokens": len(req.output)})
+            self.instant_at("finish", PID_REQUESTS, tid, req.finish_t)
+        for t in req.preempt_ts:
+            self.instant_at("preempt", PID_REQUESTS, tid, t)
 
     # ---------------------------------------------------------- metadata --
 
     def set_process_name(self, pid: int, name: str) -> None:
-        if ("p", pid) in self._named:
-            return
-        self._named.add(("p", pid))
-        self.events.append({"name": "process_name", "ph": "M", "pid": pid,
-                            "tid": 0, "args": {"name": name}})
+        self._names[(pid, None)] = name
 
     def set_thread_name(self, pid: int, tid: int, name: str) -> None:
-        if ("t", pid, tid) in self._named:
+        self._names[(pid, tid)] = name
+
+    # ----------------------------------------------------------- process --
+
+    def watch_process(self) -> "Tracer":
+        """Record the process's garbage collections (``gc``) and XLA
+        compiles (``compile``, ending when JAX reports the compile's
+        duration).  Call once per tracer."""
+        from jax import monitoring
+        gc.callbacks.append(self._on_gc)
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+        self.set_process_name(PID_PROCESS, "process")
+        return self
+
+    def _on_gc(self, phase, info) -> None:
+        if phase == "start":
+            self._gc_t0 = perf_counter()
             return
-        self._named.add(("t", pid, tid))
-        self.events.append({"name": "thread_name", "ph": "M", "pid": pid,
-                            "tid": tid, "args": {"name": name}})
+        self.complete("gc", PID_PROCESS, 0, self._gc_t0, perf_counter(),
+                      args={"gen": info["generation"],
+                            "collected": info["collected"]})
+
+    def _on_duration(self, event, secs, **_) -> None:
+        if event == COMPILE_EVENT:
+            t1 = perf_counter()
+            self.complete("compile", PID_PROCESS, 1, t1 - secs, t1)
+
+    # ------------------------------------------------------------- reads --
+
+    def _records(self) -> List:
+        while True:
+            try:
+                return list(self._ring)
+            except RuntimeError:    # appended to while being copied
+                continue
+
+    def window(self, t0: float, t1: float) -> Optional[List[Event]]:
+        """Events that overlap [t0, t1] (``perf_counter`` seconds), in the
+        order they were recorded; None when the ring may have dropped one
+        that did (it is full and its oldest record came after t0)."""
+        recs = self._records()
+        if len(recs) == self._ring.maxlen and recs[0][7] > t0:
+            return None
+        return [Event(*r[:7]) for r in recs if r[5] >= t0 and r[4] <= t1]
 
     # ------------------------------------------------------------ export --
 
-    def to_dict(self) -> Dict:
-        return {"traceEvents": list(self.events), "displayTimeUnit": "ms"}
+    def to_dict(self, t0: Optional[float] = None,
+                t1: Optional[float] = None) -> Dict:
+        """Trace-event JSON of the ring's events overlapping [t0, t1]
+        (all by default), timestamps in µs from the earliest of them."""
+        lo = float("-inf") if t0 is None else t0
+        hi = float("inf") if t1 is None else t1
+        recs = [r for r in self._records() if r[5] >= lo and r[4] <= hi]
+        base = min((r[4] for r in recs), default=0.0)
+        events = []
+        for (pid, tid), name in list(self._names.items()):
+            kind = "process_name" if tid is None else "thread_name"
+            events.append({"name": kind, "ph": "M", "pid": pid,
+                           "tid": tid or 0, "args": {"name": name}})
+        for name, ph, pid, tid, a, b, args, _ in recs:
+            ev = {"name": name, "ph": ph, "pid": pid, "tid": tid,
+                  "ts": (a - base) * 1e6}
+            if ph == "X":
+                ev["dur"] = (b - a) * 1e6
+            else:
+                ev["s"] = "t"
+            if args:
+                ev["args"] = args
+            events.append(ev)
+        return {"traceEvents": events, "displayTimeUnit": "ms"}
 
-    def export(self, path: str) -> str:
+    def export(self, path: str, t0: Optional[float] = None) -> str:
         with open(path, "w") as f:
-            json.dump(self.to_dict(), f, indent=1)
+            json.dump(self.to_dict(t0), f, indent=1)
         return path
+
+
+_RECORDER: Optional[Tracer] = None
+_RECORDER_LOCK = threading.Lock()
+
+
+def recorder() -> Tracer:
+    """The process-wide recorder, made (and its process hooks installed)
+    on first use."""
+    global _RECORDER
+    with _RECORDER_LOCK:
+        if _RECORDER is None:
+            _RECORDER = Tracer().watch_process()
+        return _RECORDER
 
 
 # ------------------------------------------------------------ validation --

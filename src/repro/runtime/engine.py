@@ -71,8 +71,8 @@ from ..core import cache as cachelib
 from ..core import mla as mlalib
 from ..core.schemes import PlatformPoint, auto_dispatch
 from ..models.common import ModelConfig
-from ..obs import OFF_TELEMETRY, Telemetry
-from ..obs.trace import PID_ENGINE
+from ..obs import Telemetry
+from ..obs.trace import PID_ENGINE, PID_REQUESTS
 from . import spec as speclib
 from .scheduler import ContinuousScheduler, Request, blocks_for
 from .steps import (make_chunked_prefill_step, make_paged_sample_step,
@@ -322,7 +322,6 @@ class PagedMLAEngine:
         self._copy_blocks = jax.jit(cachelib.copy_blocks_paged,
                                     donate_argnums=(0,))
         self._last_scheme: Optional[str] = None
-        self._last_point = (1, 1)     # (batch, cache_len) of the last pick
         self.stats = EngineStats()
         # bytes one cached token occupies across ALL layers at the POOL's
         # storage dtype — the occupancy gauges below convert allocated
@@ -333,15 +332,15 @@ class PagedMLAEngine:
             cfg.kv_lora_rank, cfg.qk_rope_dim,
             dtype_bytes=jnp.dtype(compute_dtype).itemsize,
             cache_dtype=cache_dtype)
-        # -- telemetry (repro.obs): default is the no-op singleton, whose
-        # span() returns a shared null context manager — the instrumented
-        # hot path below costs one attribute check per site when off.
-        self.tel = telemetry if telemetry is not None else OFF_TELEMETRY
-        if self.tel.drift is not None and not self.tel.drift.active \
-                and platform is not None:
-            self.tel.drift.bind(mla=self.mla, platform=platform,
-                                paged_block=block_size, dp_shards=self._dp,
-                                cache_dtype=cache_dtype)
+        # -- telemetry (repro.obs): the default records into the process
+        # recorder; Telemetry.off()'s span() returns a shared null context
+        # manager, so the instrumented hot path costs one call per site.
+        self.tel = telemetry if telemetry is not None \
+            else Telemetry.default()
+        self.sched.tracer = self.tel.tracer
+        self.tel.tracer.set_process_name(PID_ENGINE, "engine")
+        self.tel.tracer.set_thread_name(PID_ENGINE, 0, "step phases")
+        self.tel.tracer.set_process_name(PID_REQUESTS, "requests")
         if self.tel.enabled:
             self.sched.prefix.tel = self.tel
 
@@ -429,9 +428,6 @@ class PagedMLAEngine:
     def _pick_scheme(self, verify_k: int = 0) -> str:
         active = self.sched.active_slots
         cache_len = int(self.sched.lengths[active].max()) + 1 if active else 1
-        # the live dispatch point, kept for the roofline drift channel —
-        # predictions must be evaluated at the point the dispatch saw
-        self._last_point = (max(len(active), 1), cache_len)
         if self.scheme != "auto":
             self._last_scheme = self.scheme
             return self.scheme
@@ -514,9 +510,6 @@ class PagedMLAEngine:
         C = self.prefill_chunk
         step_fn = self._chunk_step(C)
         tr = self.tel.tracer
-        drift = self.tel.drift if (self.tel.drift is not None
-                                   and self.tel.drift.active) else None
-        t_pf = time.perf_counter() if drift else 0.0
         pending = dict(admitted)
         fill = {slot: req.n_cached for slot, req in admitted}
         while pending:
@@ -534,7 +527,10 @@ class PagedMLAEngine:
                 if fill[slot] >= req.plen:
                     finishing.append((slot, req))
                     del pending[slot]
-            with tr.span("prefill_chunk"):
+            with tr.span("prefill_chunk",
+                         args={"rows": int(np.count_nonzero(nv)),
+                               "tokens": int(nv.sum())},
+                         detail=functools.partial(_chunk_rows, lens, nv)):
                 logits, self.pool = step_fn(
                     self.params, jnp.asarray(tokens), self.pool,
                     jnp.asarray(self.sched.block_table), jnp.asarray(lens),
@@ -558,22 +554,6 @@ class PagedMLAEngine:
                 self._fork_and_seed(slot, logits[slot][None], step_i)
                 if self.sched.record_prefill_sample(slot, tok, step_i) is None:
                     self.pending[slot] = tok
-        if drift:
-            # one drift row per admitted batch, over the whole chunk walk
-            # (the cost model predicts a full prompt's chunk sequence);
-            # measured time includes the finishing rows' first-token
-            # sampling — a constant overhead the stable-ratio gate absorbs
-            self._sync_device()
-            seq_len = max(req.plen for _, req in admitted)
-            cached = min(req.n_cached for _, req in admitted)
-            if seq_len > cached:
-                scheme = self.scheme if self.scheme in ("seq", "rc", "ru") \
-                    else "seq"
-                impl = "pallas" if self._chunk_impl() == "kernel" \
-                    else "gather"
-                drift.record_prefill(scheme, len(admitted), seq_len, C,
-                                     impl, time.perf_counter() - t_pf,
-                                     cached_prefix=cached)
 
     def _run_per_request_prefill(self, admitted, step_i: int) -> None:
         """PR-1's path: contiguous per-request prefill (bucketed capacities
@@ -718,8 +698,6 @@ class PagedMLAEngine:
         self._process_cancels(step_i)
         was_decoding = self.sched.n_active > 0
         tr = self.tel.tracer
-        drift = self.tel.drift if (self.tel.drift is not None
-                                   and self.tel.drift.active) else None
 
         with tr.span("step"):
             with tr.span("schedule"):
@@ -757,17 +735,12 @@ class PagedMLAEngine:
                 self.stats.schemes_used[scheme] = \
                     self.stats.schemes_used.get(scheme, 0) + 1
                 step_fn = self._decode_step(scheme)
-                t_dev = time.perf_counter() if drift else 0.0
                 with tr.span("device_step"):
                     logits, self.pool = step_fn(
                         self.params, jnp.asarray(self.pending),
                         self.pool, jnp.asarray(self.sched.block_table),
                         jnp.asarray(self.sched.lengths))
                     jax.block_until_ready(self.pool)
-                if drift:
-                    b, cl = self._last_point
-                    drift.record_decode(scheme, b, cl,
-                                        time.perf_counter() - t_dev)
                 with tr.span("host_sample"):
                     picks = self._sample_tokens(logits[jnp.asarray(active)],
                                                 active)
@@ -833,8 +806,6 @@ class PagedMLAEngine:
         k = self.spec_k
         B = self.sched.max_batch
         tr = self.tel.tracer
-        drift = self.tel.drift if (self.tel.drift is not None
-                                   and self.tel.drift.active) else None
         nv = np.zeros((B,), np.int32)
         for s in active:
             nv[s] = self.sched._window(self.sched.slots[s])
@@ -880,16 +851,11 @@ class PagedMLAEngine:
         scheme = self._pick_scheme(verify_k=k)
         self.stats.schemes_used[scheme] = \
             self.stats.schemes_used.get(scheme, 0) + 1
-        t_v = time.perf_counter() if drift else 0.0
         with tr.span("verify"):
             logits_v, self.pool = self._verify_step(scheme)(
                 self.params, jnp.asarray(tokens_v), self.pool, bt,
                 jnp.asarray(self.sched.lengths), jnp.asarray(nv))
             jax.block_until_ready(self.pool)
-        if drift:
-            b, cl = self._last_point
-            drift.record_verify(scheme, b, cl, k,
-                                time.perf_counter() - t_v)
         with tr.span("host_sample"):
             if self.temperature <= 0.0:
                 target = np.asarray(jnp.argmax(logits_v, axis=-1))  # (B, k+1)
@@ -947,6 +913,13 @@ class PagedMLAEngine:
         return out
 
 
+def _chunk_rows(lens, nv) -> Dict[str, str]:
+    """Span detail of a chunk step: each row's (context, new tokens) as
+    "context:new_tokens;...", the form the decode dispatch span uses."""
+    return {"row_tokens": ";".join(f"{lens[i]}:{nv[i]}"
+                                   for i in np.nonzero(nv)[0])}
+
+
 # --------------------------------------------------------- async engine ----
 
 
@@ -956,10 +929,8 @@ class _Inflight:
     tokens: object                       # (B,) int32 device array (future)
     entries: List[Tuple[int, Request]]   # (dispatch slot, request)
     deferred: List[Tuple[int, Request]]  # slot released, token value pending
-    t_disp_tr: float                     # tracer ``now()`` clock at dispatch
-    t_disp_perf: float                   # perf_counter at dispatch (drift)
+    t_disp: float                        # tracer clock at dispatch
     scheme: str
-    point: Tuple[int, int]               # (batch, cache_len) dispatch point
     fetched: Optional[np.ndarray] = None  # host copy, once someone needed it
 
 
@@ -1005,6 +976,8 @@ class AsyncPagedMLAEngine(PagedMLAEngine):
         super().__init__(*args, **kwargs)
         self._sample_steps: Dict[str, object] = {}
         self._inflight: Optional[_Inflight] = None
+        self.tel.tracer.set_thread_name(PID_ENGINE, TID_DEVICE,
+                                        "device stream")
 
     @property
     def idle(self) -> bool:
@@ -1135,7 +1108,7 @@ class AsyncPagedMLAEngine(PagedMLAEngine):
                 self.sched.waiting.remove(req)
                 req.finished_step = step_i
                 req.finish_t = time.perf_counter()
-                self.sched.finished.append(req)
+                self.sched.retire(req)
 
     def _account(self, step_i: int) -> None:
         """Fetch and account the in-flight step's token values (the only
@@ -1145,24 +1118,15 @@ class AsyncPagedMLAEngine(PagedMLAEngine):
             return
         self._inflight = None
         tr = self.tel.tracer
-        drift = self.tel.drift if (self.tel.drift is not None
-                                   and self.tel.drift.active) else None
         with tr.span("host_sample"):
-            already = inf.fetched is not None
-            toks = inf.fetched if already else np.asarray(inf.tokens)
+            toks = inf.fetched if inf.fetched is not None \
+                else np.asarray(inf.tokens)
             if tr.enabled:
-                tr.set_thread_name(PID_ENGINE, TID_DEVICE, "device stream")
                 tr.complete(
                     "device_step", PID_ENGINE, TID_DEVICE,
-                    inf.t_disp_tr, tr.now(),
+                    inf.t_disp, tr.now(),
                     args={"scheme": inf.scheme,
                           "batch": len(inf.entries) + len(inf.deferred)})
-            if drift and not already:
-                # dispatch->ready wall: equals device time when the device
-                # is the bottleneck, an upper bound otherwise
-                b, cl = inf.point
-                drift.record_decode(inf.scheme, b, cl,
-                                    time.perf_counter() - inf.t_disp_perf)
             for slot, req in inf.entries:
                 if req.finish_reason == "cancelled" or req.slot != slot:
                     continue
@@ -1185,7 +1149,7 @@ class AsyncPagedMLAEngine(PagedMLAEngine):
                     req.finish_reason = "length"
                 req.finished_step = step_i
                 req.finish_t = time.perf_counter()
-                self.sched.finished.append(req)
+                self.sched.retire(req)
 
     def _dispatch(self, active: List[int]) -> None:
         """Launch the fused decode+sample step for the current actives and
@@ -1205,18 +1169,21 @@ class AsyncPagedMLAEngine(PagedMLAEngine):
             poss[s] = req.plen + len(req.tokens)
             entries.append((s, req))
         tr = self.tel.tracer
-        t_tr, t_perf = tr.now(), time.perf_counter()
-        # jnp.array copies: the host mutates pending / block_table / lengths
-        # while this step is in flight, and on the CPU backend jnp.asarray
-        # may alias the numpy buffer instead of copying it
-        tokens, self.pool = step_fn(
-            self.params, jnp.array(self.pending), self.pool,
-            jnp.array(self.sched.block_table),
-            jnp.array(self.sched.lengths),
-            jnp.asarray(rids), jnp.asarray(poss))
+        t_disp = tr.now()
+        lengths = self.sched.lengths
+        with tr.span("dispatch", args={"rows": len(active)},
+                     detail=lambda: {"row_tokens": ";".join(
+                         f"{lengths[s]}:1" for s in active)}):
+            # jnp.array copies: the host mutates pending / block_table /
+            # lengths while this step is in flight, and on the CPU
+            # backend jnp.asarray may alias the numpy buffer
+            tokens, self.pool = step_fn(
+                self.params, jnp.array(self.pending), self.pool,
+                jnp.array(self.sched.block_table), jnp.array(lengths),
+                jnp.asarray(rids), jnp.asarray(poss))
         self._inflight = _Inflight(
-            tokens=tokens, entries=entries, deferred=[], t_disp_tr=t_tr,
-            t_disp_perf=t_perf, scheme=scheme, point=self._last_point)
+            tokens=tokens, entries=entries, deferred=[], t_disp=t_disp,
+            scheme=scheme)
         for s in active:
             self.sched.lengths[s] += 1
             if int(self.sched.lengths[s]) % self.block_size == 0:

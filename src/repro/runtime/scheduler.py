@@ -62,6 +62,7 @@ from typing import Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..obs.trace import NULL_TRACER
 from .prefix_cache import PrefixCache
 from .sampling import SamplingParams
 
@@ -295,6 +296,9 @@ class ContinuousScheduler:
         self.cow_pending: List[Tuple[int, int]] = []
         self.fork_groups = 0        # parallel-sampling groups forked
         self.forked_children = 0    # fork children spawned across groups
+        # span recorder (repro.obs) that retire() writes each finished
+        # request's lifecycle to; the engine sets its telemetry's tracer
+        self.tracer = NULL_TRACER
 
     # ------------------------------------------------------------ queue ---
 
@@ -730,7 +734,13 @@ class ContinuousScheduler:
         req.finished_step = step
         req.finish_t = perf_counter()
         self._release_slot(slot)
+        return self.retire(req)
+
+    def retire(self, req: Request) -> Request:
+        """Account a request whose ``finish_t`` is stamped as finished,
+        and record its lifecycle."""
         self.finished.append(req)
+        self.tracer.request(req)
         return req
 
     def cancel(self, rid: int, step: int = 0) -> Optional[Request]:
@@ -744,12 +754,11 @@ class ContinuousScheduler:
         independent request and cancels alone.  Unknown or already-
         finished rids are a no-op.  Returns the cancelled request
         (``finish_reason == "cancelled"``) or None."""
-        def retire(r: Request) -> Request:
+        def cancelled(r: Request) -> Request:
             r.finish_reason = "cancelled"
             r.finished_step = step
             r.finish_t = perf_counter()
-            self.finished.append(r)
-            return r
+            return self.retire(r)
 
         for req in self.waiting:
             if req.rid == rid:
@@ -757,14 +766,14 @@ class ContinuousScheduler:
                 if not req.forked:
                     # pre-admission children exist only as attachments
                     for child in req.fork_children:
-                        retire(child)
+                        cancelled(child)
                     req.fork_children = []
-                return retire(req)
+                return cancelled(req)
             if not req.forked:
                 for child in req.fork_children:
                     if child.rid == rid:
                         req.fork_children.remove(child)
-                        return retire(child)
+                        return cancelled(child)
         for slot in self.active_slots:
             req = self.slots[slot]
             if req.rid == rid:

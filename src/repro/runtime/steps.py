@@ -420,7 +420,7 @@ def _paged_pool_shardings(cfg: ModelConfig, mesh: Mesh, rules,
 def _tag_obs(fn, *, kind: str, scheme: str, impl: str):
     """Annotate a jitted step with its dispatch identity (step kind,
     attention scheme, attention impl) so telemetry and debugging tools
-    can label spans / drift rows from the function object itself instead
+    can label spans from the function object itself instead
     of threading extra arguments.  Plain setattr: jitted callables carry
     attributes fine and ``.lower()`` (the hot-path auditor's entry) is
     unaffected."""

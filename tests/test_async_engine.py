@@ -183,9 +183,9 @@ def test_stop_sequences_across_schemes(smoke_model, scheme):
 
 def test_async_trace_nests_and_overlaps(smoke_model):
     cfg, params = smoke_model
-    tel = Telemetry.on(trace=True, metrics=False, drift=False)
+    tel = Telemetry.on(trace=True, metrics=False)
     _run(AsyncPagedMLAEngine, cfg, params, SPECS, telemetry=tel)
-    trace = tel.tracer.to_dict()
+    trace = tel.trace_dict()
     assert validate_trace(trace) == []
     evs = [e for e in trace["traceEvents"]
            if e["ph"] == "X" and e["pid"] == PID_ENGINE]

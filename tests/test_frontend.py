@@ -350,7 +350,7 @@ def test_cancel_one_fork_leaves_rest_of_group_running(smoke_model):
 
 def test_health_and_metrics_endpoints(smoke_model):
     cfg, params = smoke_model
-    tel = Telemetry.on(trace=False, metrics=True, drift=False)
+    tel = Telemetry.on(trace=False, metrics=True)
     eng = _engine(cfg, params, telemetry=tel)
     fe = Frontend(eng, port=0).start()
     try:
@@ -366,3 +366,29 @@ def test_health_and_metrics_endpoints(smoke_model):
         assert _get(fe, "/v1/health")["ok"]
     finally:
         fe.stop()
+
+
+# ----------------------------------------------------------------- spans ----
+
+
+def test_sse_lag_recorded_per_token_with_queue_shapes_unchanged(frontend):
+    """Each streamed token's write records its lag from the worker's put
+    (``sse_write``, lag_ms), and the worker records its own spans; a
+    direct queue consumer still sees the PR-9 item shapes."""
+    from repro.obs import PID_FRONTEND, recorder
+    t0 = time.perf_counter()
+    evs = _events(_post(frontend, "/v1/generate",
+                        {"prompt": PROMPT, "max_tokens": 6, "stream": True}))
+    toks = [d["token"] for e, d in evs if e == "token"]
+    w = recorder().window(t0, time.perf_counter())
+    writes = [e for e in w if e.name == "sse_write" and e.pid == PID_FRONTEND]
+    assert len(writes) == len(toks) == 6
+    assert all(e.args["lag_ms"] >= 1e3 * (e.end - e.start) >= 0
+               for e in writes)
+    names = {e.name for e in w if e.pid == PID_FRONTEND}
+    assert {"submit", "publish"} <= names
+    st = frontend.worker.submit(PROMPT, 3)
+    items = [st.q.get(timeout=60) for _ in range(4)]
+    assert [it[0] for it in items] == ["token"] * 3 + ["done"]
+    assert all(len(it) == 2 for it in items[:3]) and len(items[3]) == 3
+    assert len(st.put_t[0]) == 3

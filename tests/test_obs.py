@@ -1,4 +1,4 @@
-"""Serving telemetry subsystem (PR 7): tracer, metrics, drift, logger.
+"""Serving telemetry subsystem (PR 7): tracer, metrics, logger.
 
 Covers, bottom-up:
   * percentile / Histogram math against numpy's linear interpolation;
@@ -6,14 +6,10 @@ Covers, bottom-up:
     and the NullTracer contract (no events, export refuses);
   * disabled-mode overhead: a null span must cost well under the
     per-step budget that makes armed-off telemetry free;
-  * RooflineDrift: unbound recorder is a no-op, predictions match
-    `core.schemes.step_time` exactly (the drift channel may never
-    disagree with the dispatcher), coverage checking;
   * engine end-to-end with telemetry armed: trace validates, every
     request-lifecycle phase and step phase has a span, metrics mirror
     `engine.summary()` exactly, TTFT/TPOT histograms cover the finished
-    requests, drift covers the dispatched schemes — and outputs are
-    TOKEN-IDENTICAL to an untraced run;
+    requests — and outputs are TOKEN-IDENTICAL to an untraced run;
   * the step wall-clock fix (satellite): the engine must block on device
     work inside the step timer — jax dispatch is async, so without the
     sync `wall` measures dispatch, not compute;
@@ -29,11 +25,10 @@ import pytest
 
 import repro.configs as configs
 import repro.models as models
-from repro.core.schemes import step_time
 from repro.hwmodel.platforms import PLATFORMS
 from repro.nn import module as nnm
 from repro.obs import (NULL_TRACER, OFF_TELEMETRY, PID_ENGINE, PID_REQUESTS,
-                       Histogram, RooflineDrift, StructLogger, Telemetry,
+                       Histogram, StructLogger, Telemetry,
                        Tracer, as_logger, percentile, validate_trace)
 from repro.runtime import (BlockAllocator, PagedMLAEngine, PrefixCache,
                            Request)
@@ -137,35 +132,7 @@ def test_null_span_overhead_is_negligible():
 def test_telemetry_off_singleton():
     assert Telemetry.off() is OFF_TELEMETRY
     assert not OFF_TELEMETRY.enabled
-    assert OFF_TELEMETRY.metrics is None and OFF_TELEMETRY.drift is None
-
-
-# ------------------------------------------------------------------- drift --
-
-
-def test_drift_unbound_is_noop_and_bound_matches_dispatcher():
-    d = RooflineDrift()
-    assert not d.active
-    d.record_decode("seq", 2, 64, 0.01)
-    assert d.rows == []
-
-    mla = configs.smoke("deepseek-v2-236b").mla_config()
-    plat = PLATFORMS["tpu_v5e"]
-    d.bind(mla=mla, platform=plat, paged_block=8)
-    d.record_decode("seq", 2, 64, 0.01)
-    row = d.rows[0]
-    # the drift channel consults the EXACT function the dispatcher does
-    assert row.pred_time_s == step_time("seq", mla, plat, cache_len=64,
-                                        batch=2, paged_block=8)
-    assert row.pred_bytes > 0
-    assert row.ratio == pytest.approx(0.01 / row.pred_time_s)
-    rep = d.report()
-    assert rep["rows"] == 1
-    assert rep["kinds"]["decode"]["schemes"] == ["seq"]
-    assert "decode/seq/b2" in rep["buckets"]
-    assert d.check_coverage({"seq": 3}) == []
-    assert d.check_coverage({"rc": 1}) == \
-        ["scheme 'rc' dispatched but has no drift row"]
+    assert OFF_TELEMETRY.metrics is None
 
 
 # ---------------------------------------------------- engine end-to-end ----
@@ -203,7 +170,7 @@ def test_engine_telemetry_end_to_end(smoke_model):
     eng = _run(cfg, params, reqs, telemetry=tel)
     tel.finalize(eng)
 
-    trace = tel.tracer.to_dict()
+    trace = tel.trace_dict()
     assert validate_trace(trace) == []
 
     def names(pid):
@@ -232,17 +199,10 @@ def test_engine_telemetry_end_to_end(smoke_model):
     for r in eng.sched.finished:
         assert 0 <= r.submit_t <= r.admit_t <= r.first_tok_t <= r.finish_t
 
-    # drift rows exist for every dispatched scheme and the report holds
-    assert tel.drift.check_coverage(summ["schemes_used"],
-                                    kinds=("decode",)) == []
-    rep = tel.drift.report()
-    assert rep["rows"] == len(tel.drift.rows) > 0
-    assert {"decode", "prefill"} <= set(rep["kinds"])
-
     # finalize is idempotent: a second call must not duplicate spans
     n_events = len(trace["traceEvents"])
     tel.finalize(eng)
-    assert len(tel.tracer.to_dict()["traceEvents"]) == n_events
+    assert len(tel.trace_dict()["traceEvents"]) == n_events
 
     # the registry round-trips through JSON (the --metrics artifact)
     d = json.loads(json.dumps(m.to_dict()))
@@ -315,14 +275,14 @@ def test_as_logger_adapts_legacy_callables():
 
 def test_prefix_cache_evict_and_cow_instants():
     pc = PrefixCache(BlockAllocator(4), 4)
-    tel = Telemetry.on(trace=True, metrics=True, drift=False)
+    tel = Telemetry.on(trace=True, metrics=True)
     pc.tel = tel
     blocks = pc.alloc(2)
     pc.insert(list(range(8)), blocks)
     pc.release(blocks)                    # refcount 0 -> LRU-evictable
     assert pc.evict(2) == 2
     pc.count_cow()
-    names = [e["name"] for e in tel.tracer.to_dict()["traceEvents"]]
+    names = [e["name"] for e in tel.trace_dict()["traceEvents"]]
     assert "prefix_evict" in names and "cow_copy" in names
     assert tel.metrics.counter("prefix_cache.evictions").value == 2
     assert tel.metrics.counter("prefix_cache.cow_copies").value == 1

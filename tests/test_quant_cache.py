@@ -38,7 +38,6 @@ from repro.kernels.mla_decode import (RESCALES, exp_add_rescale,
 from repro.kernels.mla_prefill import mla_prefill_paged_kernel
 from repro.nn import module as nnm
 from repro.obs import Telemetry
-from repro.obs.drift import RooflineDrift
 from repro.runtime import BlockAllocator, PagedMLAEngine, Request
 
 pytestmark = pytest.mark.kernel
@@ -426,28 +425,6 @@ def test_bytes_per_token_and_schemes_cache_width():
     s = schemeslib.auto_dispatch(ac.DSV3_MLA, plat, cache_len=4096, batch=8,
                                  paged_block=64, cache_dtype="int8")
     assert s in ("seq", "rc", "ru")
-
-
-# ------------------------------------------------- drift/telemetry dtype pin
-
-
-def test_drift_predictions_are_dispatcher_exact_for_quantized_pool():
-    """Satellite fix pin: a drift channel bound with cache_dtype must
-    price the quantized cache stream (modeled bytes AND time shrink) and
-    stamp the dtype into its report."""
-    plat = PLATFORMS["tpu_v5e"]
-    rows = {}
-    for cd in (None, "int8"):
-        d = RooflineDrift(mla=ac.DSV3_MLA, platform=plat, paged_block=128,
-                          cache_dtype=cd)
-        d.record_decode("seq", 16, 4096, 1e-3)
-        rows[cd] = d.rows[0]
-        assert d.report()["cache_dtype"] == (cd or "bf16")
-    assert rows["int8"].pred_bytes < rows[None].pred_bytes
-    assert rows["int8"].pred_time_s < rows[None].pred_time_s
-    assert rows["int8"].pred_time_s == pytest.approx(
-        schemeslib.step_time("seq", ac.DSV3_MLA, plat, cache_len=4096,
-                             batch=16, paged_block=128, cache_dtype="int8"))
 
 
 # ----------------------------------------------------------- engine, e2e ---
