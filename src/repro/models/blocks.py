@@ -52,6 +52,18 @@ class Ctx:
     n_valid: Any = None             # (B,) int32 — chunked prefill only
 
 
+def _paged_valid(ctx: Ctx, h):
+    """The real tokens of a paged serving step, for the MoE: a decode
+    row with a cache (length > 0: empty slots and rows still prefilling
+    carry 0), a chunk or verify position below its row's ``n_valid``.
+    None outside paged steps, where every token is real."""
+    if ctx.lengths is None:
+        return None
+    if ctx.n_valid is None:
+        return jnp.asarray(ctx.lengths) > 0
+    return jnp.arange(h.shape[1])[None, :] < jnp.asarray(ctx.n_valid)[:, None]
+
+
 # ------------------------------------------------------------------ defs ---
 
 
@@ -430,7 +442,8 @@ def sub_apply(params, cfg: ModelConfig, desc: Sub, x, ctx: Ctx):
         h = nl.rmsnorm(params["ln2"], x, cfg.norm_eps)
         if desc.ffn == "moe":
             f, aux = moelib.moe_apply(params["ffn"], cfg, h, mesh=ctx.mesh,
-                                      shard_mode=ctx.shard_mode)
+                                      shard_mode=ctx.shard_mode,
+                                      valid=_paged_valid(ctx, h))
             aux = {k: jnp.asarray(aux[k], jnp.float32) for k in ZERO_AUX}
         else:
             f = nl.mlp(params["ffn"], h, kind=cfg.mlp_kind)

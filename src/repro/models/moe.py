@@ -10,7 +10,9 @@ one-hot dispatch tensor of the GShard formulation, which at
 T=32k, E=160, C=1.5k would be ~16 GB/device.
 
 Capacity: C = ceil(T_local * top_k / E * capacity_factor); overflow tokens
-are dropped (standard token-choice semantics).  Router aux losses
+are dropped (standard token-choice semantics).  Padding tokens of a
+serving step (``valid`` False: empty slots, chunk positions past a row's
+prompt) take no capacity, so they never displace a real token.  Router aux losses
 (load-balance + z-loss) are returned for the trainer.
 """
 from __future__ import annotations
@@ -46,11 +48,12 @@ def _capacity(t_local: int, cfg: ModelConfig) -> int:
                                 * cfg.capacity_factor)))
 
 
-def _moe_local(x, router_w, gate_up, down, *, cfg: ModelConfig,
+def _moe_local(x, valid, router_w, gate_up, down, *, cfg: ModelConfig,
                model_axis: Optional[str], f_axis: Optional[str] = None):
-    """x: (T, D) local tokens (replicated over model axis); gate_up/down are
-    the LOCAL expert shard (possibly also F-sharded over ``f_axis`` in the
-    serve_2dtp layout). Returns (out (T,D) partial-summed, aux dict)."""
+    """x: (T, D) local tokens (replicated over model axis), valid: (T,)
+    bool; gate_up/down are the LOCAL expert shard (possibly also F-sharded
+    over ``f_axis`` in the serve_2dtp layout). Returns (out (T,D)
+    partial-summed, aux dict)."""
     T, D = x.shape
     E, k = cfg.n_experts, cfg.top_k
     E_local = gate_up.shape[0]
@@ -68,14 +71,15 @@ def _moe_local(x, router_w, gate_up, down, *, cfg: ModelConfig,
         z_loss = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
 
         # ---- sort-based slot assignment ------------------------------------
-        flat_e = sel.reshape(-1)                                      # (T*k,)
+        # padding routes to expert E, past every real expert's segment
+        flat_e = jnp.where(jnp.repeat(valid, k), sel.reshape(-1), E)  # (T*k,)
         flat_t = jnp.repeat(jnp.arange(T), k)
         flat_g = gate_vals.reshape(-1)
         order = jnp.argsort(flat_e)
         se, st, sg = flat_e[order], flat_t[order], flat_g[order]
         seg_start = jnp.searchsorted(se, jnp.arange(E))
         pos = jnp.arange(T * k) - seg_start[se]
-        keep = pos < C
+        keep = (pos < C) & (se < E)
 
         tok_tbl = jnp.full((E, C), T, jnp.int32)                      # T = pad row
         tok_tbl = tok_tbl.at[se, pos].set(jnp.where(keep, st, T), mode="drop")
@@ -106,9 +110,12 @@ def _moe_local(x, router_w, gate_up, down, *, cfg: ModelConfig,
 
 
 def moe_apply(params, cfg: ModelConfig, x, *, mesh=None,
-              shard_mode: str = "train") -> Tuple[jax.Array, Dict]:
+              shard_mode: str = "train",
+              valid=None) -> Tuple[jax.Array, Dict]:
     """x: (B, L, D) or (B, D). Shared experts (dense, TP-sharded) computed
-    outside the shard_map; routed experts inside (EP).
+    outside the shard_map; routed experts inside (EP).  ``valid`` (x's
+    shape without D, bool) marks a serving step's real tokens; padding
+    gets no routed expert and takes no capacity.  None: every token.
 
     shard_mode='serve_2dtp': tokens replicated (decode activations are
     KB-sized), expert bank 2D-sharded (experts over 'model', F over
@@ -117,6 +124,8 @@ def moe_apply(params, cfg: ModelConfig, x, *, mesh=None,
     reduction (EXPERIMENTS.md §Perf A1)."""
     shape = x.shape
     x2 = x.reshape(-1, shape[-1]) if x.ndim == 3 else x
+    v2 = jnp.ones(x2.shape[:1], bool) if valid is None \
+        else jnp.reshape(valid, (-1,))
 
     if mesh is not None and "model" in mesh.axis_names:
         from jax.sharding import PartitionSpec as PS
@@ -124,16 +133,16 @@ def moe_apply(params, cfg: ModelConfig, x, *, mesh=None,
         if shard_mode == "serve_2dtp":
             f_ax = "data" if "data" in mesh.axis_names and \
                 cfg.moe_d_ff % sizes.get("data", 1) == 0 else None
-            fn = lambda xl, rw, gu, dn: _moe_local(
-                xl, rw, gu, dn, cfg=cfg, model_axis="model", f_axis=f_ax)
+            fn = lambda xl, vl, rw, gu, dn: _moe_local(
+                xl, vl, rw, gu, dn, cfg=cfg, model_axis="model", f_axis=f_ax)
             out, aux = jax.shard_map(
                 fn, mesh=mesh,
-                in_specs=(PS(None, None), PS(None, None),
+                in_specs=(PS(None, None), PS(None), PS(None, None),
                           PS("model", None, None, f_ax),
                           PS("model", f_ax, None)),
                 out_specs=(PS(None, None), PS()),
                 check_vma=False,
-            )(x2, params["router"], params["gate_up"], params["down"])
+            )(x2, v2, params["router"], params["gate_up"], params["down"])
             if cfg.n_shared_experts:
                 with jax.named_scope("moe_shared_experts"):
                     out = out + nl.mlp(params["shared"], x2, kind="swiglu")
@@ -144,19 +153,19 @@ def moe_apply(params, cfg: ModelConfig, x, *, mesh=None,
             dp_size *= sizes[a]
         if x2.shape[0] % dp_size != 0:   # e.g. batch=1 long-decode
             dp = ()
-        fn = lambda xl, rw, gu, dn: _moe_local(xl, rw, gu, dn, cfg=cfg,
-                                               model_axis="model")
+        fn = lambda xl, vl, rw, gu, dn: _moe_local(
+            xl, vl, rw, gu, dn, cfg=cfg, model_axis="model")
         # tokens sharded over DP (flattened B*L), replicated over model;
         # experts sharded over model; router replicated.
         out, aux = jax.shard_map(
             fn, mesh=mesh,
-            in_specs=(PS(dp or None, None), PS(None, None),
+            in_specs=(PS(dp or None, None), PS(dp or None), PS(None, None),
                       PS("model", None, None, None), PS("model", None, None)),
             out_specs=(PS(dp or None, None), PS()),
             check_vma=False,
-        )(x2, params["router"], params["gate_up"], params["down"])
+        )(x2, v2, params["router"], params["gate_up"], params["down"])
     else:
-        out, aux = _moe_local(x2, params["router"], params["gate_up"],
+        out, aux = _moe_local(x2, v2, params["router"], params["gate_up"],
                               params["down"], cfg=cfg, model_axis=None)
 
     if cfg.n_shared_experts:

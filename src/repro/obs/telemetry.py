@@ -36,7 +36,8 @@ from .trace import NULL_TRACER, Tracer, recorder
 # registry "subsumes EngineStats" — parity is pinned in tests/test_obs.py)
 _ENGINE_COUNTERS = (
     "steps", "decode_tokens", "prefill_tokens", "prompt_tokens",
-    "prefill_chunks", "admissions", "mid_gen_admissions", "preemptions",
+    "prefill_chunks", "interleaved_chunks", "admissions",
+    "mid_gen_admissions", "preemptions",
     "scheme_switches", "spec_rounds", "spec_drafted", "spec_accepted",
     "fork_groups", "fork_children",
 )

@@ -8,12 +8,15 @@ cache, block tables, eviction) to the jitted device steps:
     fixed-size chunk by chunk, attending their prefix-cache hits through
     the block table — one compiled step shape per chunk size instead of
     one retrace per prompt length, and no contiguous-entries detour.
+    While any row decodes, a tick runs ONE chunk step before its decode
+    step, so running rows wait one chunk, not a whole prompt
+    (``_run_chunked_prefill``).
     (``prefill_mode='per_request'`` keeps PR-1's bucketed per-request
     prefill + scatter for A/B comparison; it forces the prefix cache off
     because it recomputes and rewrites whole prompts.)
   * one paged decode step per scheduler tick over ALL slots (inactive
-    slots ride along pointing at the null block; their logits are
-    discarded);
+    slots, and rows still prefilling, ride along as empty rows pointing
+    at the null block; their logits are discarded);
   * sampling: greedy argmax by default; ``temperature > 0`` switches to
     temperature / top-k sampling with a per-request PRNG key folded with
     the ABSOLUTE token position, so recompute-preemption replay remains
@@ -100,6 +103,7 @@ class EngineStats:
     spec_slot_rounds: int = 0       # per-slot verify rows across rounds
     spec_drafted: int = 0           # draft tokens proposed
     spec_accepted: int = 0          # draft tokens accepted by the target
+    interleaved_chunks: int = 0     # chunk steps run while rows decoded
     util_valid_sum: float = 0.0     # time-avg of valid/allocated
     util_pool_sum: float = 0.0
     util_samples: int = 0
@@ -114,6 +118,7 @@ class EngineStats:
             "prefill_tokens": self.prefill_tokens,
             "prompt_tokens": self.prompt_tokens,
             "prefill_chunks": self.prefill_chunks,
+            "interleaved_chunks": self.interleaved_chunks,
             "admissions": self.admissions,
             "mid_gen_admissions": self.mid_gen_admissions,
             "preemptions": self.preemptions,
@@ -311,6 +316,10 @@ class PagedMLAEngine:
             # (absorbed leaves attached / mesh-committed above)
             self.draft_params = self.params
         self.pending = np.zeros((max_batch,), np.int32)   # next token to feed
+        # (chunk logits, [(slot, request)]) of rows whose prompt the last
+        # tick's chunk step ended while rows decoded: they sample their
+        # first token at the start of this tick's chunk work
+        self._ended: Optional[Tuple[object, List[Tuple[int, Request]]]] = None
         self._decode_steps: Dict[str, object] = {}
         self._prefills: Dict[int, object] = {}     # per_request: cap -> fn
         self._chunk_steps: Dict[int, object] = {}  # chunked: chunk size -> fn
@@ -501,35 +510,60 @@ class PagedMLAEngine:
 
     # --------------------------------------------------------- prefill ----
 
-    def _run_chunked_prefill(self, admitted, step_i: int) -> None:
-        """Prefill every just-admitted request's UN-CACHED prompt suffix as
-        a batch, ``prefill_chunk`` tokens per request per step, scattering
-        latents straight into the pool.  Rows that exhaust their prompt in
-        a chunk sample generated token #1 from that chunk's last-valid
-        logits and register their blocks in the radix cache."""
+    def _prefill_stage(self, admitted, step_i: int,
+                       was_decoding: bool) -> None:
+        """The tick's prefill stage, one path for both engines: account
+        this tick's admissions, then prefill — chunked, one tick's chunk
+        work over every prefilling row (:meth:`_run_chunked_prefill`); per
+        request, each just-admitted prompt whole."""
+        for _, req in admitted:
+            self.stats.admissions += 1
+            self.stats.prompt_tokens += req.plen
+            if was_decoding:
+                self.stats.mid_gen_admissions += 1
+        chunked = self.prefill_mode == "chunked"
+        if not (self.sched.prefilling if chunked else admitted):
+            return
+        with self.tel.tracer.span("prefill"):
+            if chunked:
+                self._run_chunked_prefill(step_i)
+            else:
+                self._run_per_request_prefill(admitted, step_i)
+        # fork-group tail copies queued by fork_group must land
+        # before both forks' decode writes dispatch
+        self._drain_cow()
+
+    def _run_chunked_prefill(self, step_i: int) -> None:
+        """One tick's chunk work over the scheduler's prefilling rows.
+        A chunk step advances every prefilling row by up to
+        ``prefill_chunk`` UN-CACHED prompt tokens — rows admitted this
+        tick beside rows part-way through — scattering latents straight
+        into the pool.  While any row decodes, the tick runs ONE chunk
+        step and its decode step follows, so running rows wait one chunk
+        step, not a whole prompt; rows whose prompt that chunk ends take
+        their first token at the start of the next tick's chunk work
+        (:meth:`_first_tokens`), since the decode step dispatched behind
+        the chunk would otherwise hold the host until both ran.  With
+        none decoding nothing waits: chunk steps run back to back, and
+        rows take their first token as their prompt ends, until a row
+        decodes or none is left prefilling."""
         C = self.prefill_chunk
         step_fn = self._chunk_step(C)
         tr = self.tel.tracer
-        pending = dict(admitted)
-        fill = {slot: req.n_cached for slot, req in admitted}
-        while pending:
-            tokens = np.zeros((self.sched.max_batch, C), np.int32)
-            lens = np.zeros((self.sched.max_batch,), np.int32)
-            nv = np.zeros((self.sched.max_batch,), np.int32)
-            finishing = []
-            for slot, req in list(pending.items()):
-                start = fill[slot]
-                take = min(req.plen - start, C)
-                tokens[slot, :take] = req.prompt[start:start + take]
-                lens[slot] = start
-                nv[slot] = take
-                fill[slot] = start + take
-                if fill[slot] >= req.plen:
-                    finishing.append((slot, req))
-                    del pending[slot]
+        if self._ended is not None:
+            # rows preempted or cancelled since have left their slot
+            logits, rows = self._ended
+            self._ended = None
+            self._first_tokens(logits, [
+                s for s, req in rows if self.sched.slots[s] is req
+                and self.sched.prefilling.get(s) == req.plen], step_i)
+        while self.sched.prefilling:
+            decoding = self.sched.n_active
+            tokens, lens, nv, finishing = self.sched.next_chunk(C)
             with tr.span("prefill_chunk",
                          args={"rows": int(np.count_nonzero(nv)),
-                               "tokens": int(nv.sum())},
+                               "tokens": int(nv.sum()),
+                               "decoding": decoding},
                          detail=functools.partial(_chunk_rows, lens, nv)):
                 logits, self.pool = step_fn(
                     self.params, jnp.asarray(tokens), self.pool,
@@ -547,13 +581,28 @@ class PagedMLAEngine:
                         jnp.asarray(lens), jnp.asarray(nv))
             self.stats.prefill_tokens += int(nv.sum())
             self.stats.prefill_chunks += 1
-            for slot, req in finishing:
-                tok = self._sample_tokens(logits[slot][None], [slot])[slot]
-                # register blocks only now — their latents are in the pool
-                self.sched.commit_prefill(slot)
-                self._fork_and_seed(slot, logits[slot][None], step_i)
-                if self.sched.record_prefill_sample(slot, tok, step_i) is None:
-                    self.pending[slot] = tok
+            if decoding:
+                self.stats.interleaved_chunks += 1
+                if finishing:
+                    self._ended = (logits, [(s, self.sched.slots[s])
+                                            for s in finishing])
+                break
+            self._first_tokens(logits, finishing, step_i)
+            if self.sched.n_active:
+                break
+
+    def _first_tokens(self, logits, slots, step_i: int) -> None:
+        """Rows whose prompt ended in the chunk step that returned
+        ``logits`` sample generated token #1 from their last-valid row,
+        register their blocks in the radix cache, fork their group and
+        decode from this tick on."""
+        for slot in slots:
+            tok = self._sample_tokens(logits[slot][None], [slot])[slot]
+            # register blocks only now — their latents are in the pool
+            self.sched.commit_prefill(slot)
+            self._fork_and_seed(slot, logits[slot][None], step_i)
+            if self.sched.record_prefill_sample(slot, tok, step_i) is None:
+                self.pending[slot] = tok
 
     def _run_per_request_prefill(self, admitted, step_i: int) -> None:
         """PR-1's path: contiguous per-request prefill (bucketed capacities
@@ -691,8 +740,8 @@ class PagedMLAEngine:
             jax.block_until_ready(self.draft_pool)
 
     def step(self) -> None:
-        """One scheduler tick: admit + batched prefill, then one batched
-        decode step over all slots."""
+        """One scheduler tick: admit, the prefill stage (one chunk step
+        while rows decode), then one batched decode step over all slots."""
         t0 = time.perf_counter()
         step_i = self.stats.steps
         self._process_cancels(step_i)
@@ -712,20 +761,7 @@ class PagedMLAEngine:
                 # partial-hit tail copies queued by try_admit must land
                 # before prefill gathers/writes touch the pool
                 self._drain_cow()
-            for _, req in admitted:
-                self.stats.admissions += 1
-                self.stats.prompt_tokens += req.plen
-                if was_decoding:
-                    self.stats.mid_gen_admissions += 1
-            if admitted:
-                with tr.span("prefill"):
-                    if self.prefill_mode == "chunked":
-                        self._run_chunked_prefill(admitted, step_i)
-                    else:
-                        self._run_per_request_prefill(admitted, step_i)
-                # fork-group tail copies queued by fork_group must land
-                # before both forks' decode writes dispatch
-                self._drain_cow()
+            self._prefill_stage(admitted, step_i, was_decoding)
 
             active = self.sched.active_slots
             if active and self.spec_k:
@@ -735,11 +771,11 @@ class PagedMLAEngine:
                 self.stats.schemes_used[scheme] = \
                     self.stats.schemes_used.get(scheme, 0) + 1
                 step_fn = self._decode_step(scheme)
+                table, lengths = self.sched.decode_view()
                 with tr.span("device_step"):
                     logits, self.pool = step_fn(
                         self.params, jnp.asarray(self.pending),
-                        self.pool, jnp.asarray(self.sched.block_table),
-                        jnp.asarray(self.sched.lengths))
+                        self.pool, jnp.asarray(table), jnp.asarray(lengths))
                     jax.block_until_ready(self.pool)
                 with tr.span("host_sample"):
                     picks = self._sample_tokens(logits[jnp.asarray(active)],
@@ -812,8 +848,9 @@ class PagedMLAEngine:
         # ---- 1. draft ---------------------------------------------------
         drafts = np.zeros((B, k), np.int32)
         d_pending = self.pending.copy()
-        d_lens = self.sched.lengths.copy()
-        bt = jnp.asarray(self.sched.block_table)
+        table, lengths = self.sched.decode_view()
+        d_lens = lengths.copy()
+        bt = jnp.asarray(table)
         d_step = self._draft_step()
         with tr.span("draft"):
             for j in range(int(nv.max())):
@@ -854,7 +891,7 @@ class PagedMLAEngine:
         with tr.span("verify"):
             logits_v, self.pool = self._verify_step(scheme)(
                 self.params, jnp.asarray(tokens_v), self.pool, bt,
-                jnp.asarray(self.sched.lengths), jnp.asarray(nv))
+                jnp.asarray(lengths), jnp.asarray(nv))
             jax.block_until_ready(self.pool)
         with tr.span("host_sample"):
             if self.temperature <= 0.0:
@@ -950,9 +987,15 @@ class AsyncPagedMLAEngine(PagedMLAEngine):
          prefill device ops enqueue AFTER the in-flight step in stream
          order, so the device-side op sequence is exactly the synchronous
          engine's.
-      2. *prefill* — admitted prompts chunk-prefill (this syncs on the
-         finishing rows' logits, serializing the tick — admission ticks
-         pay the pipeline bubble, steady-state decode ticks don't).
+      2. *prefill* — while rows decode, ONE chunk step over every
+         prefilling row (:meth:`PagedMLAEngine._run_chunked_prefill`),
+         enqueued behind the in-flight decode step; the next decode step
+         enqueues behind it, with no host sync.  Rows whose prompt that
+         chunk ended sample their first token at the start of the next
+         tick's prefill (the chunk has run by then, or runs while the
+         decode step behind it waits) and join that tick's dispatch.
+         With no row decoding, chunk steps run back to back until a row
+         decodes.
       3. *host_sample* — fetch the in-flight (B,) token array (the only
          device->host transfer; blocks for however much device time the
          host did NOT overlap), emit the retrospective ``device_step``
@@ -1019,20 +1062,7 @@ class AsyncPagedMLAEngine(PagedMLAEngine):
                 # partial-hit tail copies queued by try_admit must land
                 # before prefill gathers/writes touch the pool
                 self._drain_cow()
-            for _, req in admitted:
-                self.stats.admissions += 1
-                self.stats.prompt_tokens += req.plen
-                if was_decoding:
-                    self.stats.mid_gen_admissions += 1
-            if admitted:
-                with tr.span("prefill"):
-                    if self.prefill_mode == "chunked":
-                        self._run_chunked_prefill(admitted, step_i)
-                    else:
-                        self._run_per_request_prefill(admitted, step_i)
-                # fork-group tail copies queued by fork_group must land
-                # before both forks' decode writes dispatch
-                self._drain_cow()
+            self._prefill_stage(admitted, step_i, was_decoding)
 
             self._account(step_i)
 
@@ -1170,7 +1200,7 @@ class AsyncPagedMLAEngine(PagedMLAEngine):
             entries.append((s, req))
         tr = self.tel.tracer
         t_disp = tr.now()
-        lengths = self.sched.lengths
+        table, lengths = self.sched.decode_view()
         with tr.span("dispatch", args={"rows": len(active)},
                      detail=lambda: {"row_tokens": ";".join(
                          f"{lengths[s]}:1" for s in active)}):
@@ -1179,7 +1209,7 @@ class AsyncPagedMLAEngine(PagedMLAEngine):
             # backend jnp.asarray may alias the numpy buffer
             tokens, self.pool = step_fn(
                 self.params, jnp.array(self.pending), self.pool,
-                jnp.array(self.sched.block_table), jnp.array(lengths),
+                jnp.array(table), jnp.array(lengths),
                 jnp.asarray(rids), jnp.asarray(poss))
         self._inflight = _Inflight(
             tokens=tokens, entries=entries, deferred=[], t_disp=t_disp,

@@ -17,7 +17,11 @@ numpy, between jitted steps:
     granular: a hit may end mid-block, materialized by a queued
     copy-on-write of the partial source block — and maps the request's
     leading block-table entries onto the shared pool blocks, so only the
-    un-cached suffix needs prefilling (``Request.n_cached``).  Admission
+    un-cached suffix needs prefilling (``Request.n_cached``).  An
+    admitted row then PREFILLS across engine ticks (``prefilling``: slot
+    -> next prompt position; ``next_chunk`` lays out each chunk step)
+    and joins ``active_slots`` — the rows a decode step serves — at
+    ``commit_prefill``.  Admission
     order is ``admission='fcfs'`` (strict) or ``'cache_aware'``
     (longest-cached-prefix first with an ``admission_age_bound``
     starvation bound).  Each decode step lazily allocates one more block
@@ -294,6 +298,11 @@ class ContinuousScheduler:
         # pool write (copy-on-write breaks of shared write targets,
         # partial-match tails, fork-group tails)
         self.cow_pending: List[Tuple[int, int]] = []
+        # admitted rows still prefilling their prompt: slot -> next prompt
+        # position.  They (and fork children seated beside them) hold
+        # their slot and blocks but are not in active_slots until
+        # commit_prefill (fork_group, for the children).
+        self.prefilling: Dict[int, int] = {}
         self.fork_groups = 0        # parallel-sampling groups forked
         self.forked_children = 0    # fork children spawned across groups
         # span recorder (repro.obs) that retire() writes each finished
@@ -320,7 +329,11 @@ class ContinuousScheduler:
 
     @property
     def active_slots(self) -> List[int]:
-        return [s for s in range(self.max_batch) if self.slots[s] is not None]
+        """Slots the next decode step serves: occupied and not held by a
+        prefill (:meth:`_held`)."""
+        held = self._held()
+        return [s for s in range(self.max_batch)
+                if self.slots[s] is not None and s not in held]
 
     @property
     def n_active(self) -> int:
@@ -328,7 +341,27 @@ class ContinuousScheduler:
 
     @property
     def all_done(self) -> bool:
-        return not self.waiting and not self.active_slots
+        return not self.waiting and all(r is None for r in self.slots)
+
+    def _seated_children(self, slot: int) -> List[int]:
+        """Slots of the fork children seated beside the request in
+        ``slot`` that ``fork_group`` has not mapped yet."""
+        req = self.slots[slot]
+        return [] if req.forked else [c.slot for c in req.fork_children]
+
+    def _held(self) -> set:
+        """Occupied slots that do not decode yet: prefilling rows and
+        the fork children seated beside them."""
+        held = set(self.prefilling)
+        for slot in self.prefilling:
+            held.update(self._seated_children(slot))
+        return held
+
+    def _parent_of(self, slot: int) -> Optional[int]:
+        """The prefilling parent's slot of an unforked child in ``slot``,
+        else None."""
+        return next((p for p in self.prefilling
+                     if slot in self._seated_children(p)), None)
 
     # -------------------------------------------------------- admission ---
 
@@ -380,9 +413,11 @@ class ContinuousScheduler:
 
         If the pool cannot cover the picked request even after LRU
         eviction, admission stops.  Returns [(slot, request)] admitted
-        now — parents only, fork children never prefill; the engine
-        prefills the un-cached suffixes as a batch and then calls
-        ``commit_prefill`` (+ ``fork_group``) per request."""
+        now — parents only, fork children never prefill.  Each admitted
+        row enters ``prefilling`` at its first un-cached position; the
+        engine prefills the un-cached suffixes chunk by chunk
+        (``next_chunk``) and calls ``commit_prefill`` (+ ``fork_group``)
+        per request as its prompt ends."""
         admitted = []
         free = collections.deque(
             s for s in range(self.max_batch) if self.slots[s] is None)
@@ -431,6 +466,7 @@ class ContinuousScheduler:
             if req.admit_t < 0:
                 req.admit_t = perf_counter()
             req.n_cached = shared.n_tokens(self.block_size)
+            self.prefilling[slot] = req.n_cached
             self.slots[slot] = req
             self.blocks_of[slot] = blocks
             self.block_table[slot] = NULL_BLOCK
@@ -510,12 +546,48 @@ class ContinuousScheduler:
                                                    "n": 1 + len(out)})
         return out
 
+    def next_chunk(self, chunk: int) -> Tuple[np.ndarray, np.ndarray,
+                                              np.ndarray, List[int]]:
+        """Lay out the next chunk step over every prefilling row and
+        advance their positions: (tokens (B, chunk), lens (B,) — each
+        row's context before the chunk, nv (B,) — its valid tokens,
+        0 for rows not prefilling, and the slots whose prompt this chunk
+        ends).  A row stays in ``prefilling`` until ``commit_prefill``."""
+        B = self.max_batch
+        tokens = np.zeros((B, chunk), np.int32)
+        lens = np.zeros((B,), np.int32)
+        nv = np.zeros((B,), np.int32)
+        finishing = []
+        for slot, start in self.prefilling.items():
+            req = self.slots[slot]
+            take = min(req.plen - start, chunk)
+            tokens[slot, :take] = req.prompt[start:start + take]
+            lens[slot], nv[slot] = start, take
+            self.prefilling[slot] = start + take
+            if start + take >= req.plen:
+                finishing.append(slot)
+        return tokens, lens, nv, finishing
+
+    def decode_view(self) -> Tuple[np.ndarray, np.ndarray]:
+        """(block_table, lengths) as a decode step must see them: rows
+        that do not decode yet (:meth:`_held`) read as empty slots — a
+        null table and length 0 — so the step neither attends nor writes
+        their half-filled blocks.  The live arrays when no row is held."""
+        held = sorted(self._held())
+        if not held:
+            return self.block_table, self.lengths
+        table, lengths = self.block_table.copy(), self.lengths.copy()
+        table[held] = NULL_BLOCK
+        lengths[held] = 0
+        return table, lengths
+
     def commit_prefill(self, slot: int) -> int:
-        """Register the request's full prompt blocks in the radix cache.
-        MUST be called only after the engine's prefill has scattered the
-        corresponding latents into the pool — matches hand out pool
-        contents, not promises.  Returns the number of blocks newly
-        registered."""
+        """End the row's prefill and register its full prompt blocks in
+        the radix cache.  MUST be called only after the engine's prefill
+        has scattered the corresponding latents into the pool — matches
+        hand out pool contents, not promises.  Returns the number of
+        blocks newly registered."""
+        self.prefilling.pop(slot, None)
         req = self.slots[slot]
         n_full = req.plen // self.block_size
         return self.prefix.insert(req.prompt, self.blocks_of[slot][:n_full])
@@ -573,7 +645,9 @@ class ContinuousScheduler:
         window = ``decode_window`` budget-clipped) has blocks.  Oldest
         admissions grow first; on pool exhaustion the cache is
         LRU-evicted, then the YOUNGEST running request is preempted
-        (recompute-style) so the oldest always make progress.  If a
+        (recompute-style) so the oldest always make progress.  Rows
+        still prefilling hold the blocks of their prompt and first write
+        window from admission, so they never grow.  If a
         write-target block turns out shared (prefix-forked or
         trie-registered), the share is broken copy-on-write: a private
         block is allocated and the (src, dst) device copy is queued on
@@ -589,13 +663,12 @@ class ContinuousScheduler:
             while need > len(self.blocks_of[slot]):
                 got = self.prefix.alloc(1)
                 if got is None:
-                    if self.n_active <= 1:
+                    if len(self._admit_order) <= 1:
                         raise RuntimeError(
                             "pool exhausted with a single running request; "
                             "increase num_blocks or max cache length")
-                    victim, vslot = self._preempt_youngest()
-                    preempted.append(victim)
-                    if vslot == slot:     # preempted ourselves: stop growing
+                    preempted.append(self._preempt_youngest())
+                    if self.slots[slot] is None:  # preempted ourselves
                         break
                     continue
                 self.blocks_of[slot].extend(got)
@@ -635,8 +708,17 @@ class ContinuousScheduler:
         out, self.cow_pending = self.cow_pending, []
         return out
 
-    def _preempt_youngest(self) -> Tuple[Request, int]:
+    def _preempt_youngest(self) -> Request:
+        """Preempt the youngest admission.  An unforked child goes with
+        its prefilling parent, and a prefilling parent takes its seated
+        children with it: they ride on it through the queue again."""
         slot = self._admit_order[-1]
+        parent = self._parent_of(slot)
+        if parent is not None:
+            slot = parent
+        if slot in self.prefilling:
+            for cslot in self._seated_children(slot):
+                self._release_slot(cslot)
         req = self.slots[slot]
         # recompute-style: prompt + generated so far re-enter the queue as
         # a longer prompt (per-position-keyed sampling makes the replay
@@ -649,7 +731,7 @@ class ContinuousScheduler:
         req.preempt_ts.append(perf_counter())
         self._release_slot(slot)
         self.waiting.appendleft(req)
-        return req, slot
+        return req
 
     def record_prefill_sample(self, slot: int, tok: int,
                               step: int = 0) -> Optional[Request]:
@@ -748,10 +830,12 @@ class ContinuousScheduler:
         queue; running requests release their slot and blocks (trie-
         registered prefix blocks stay cached and unpoisoned — the pool
         contents they index are still valid prompt latents).  Fork
-        groups: cancelling a not-yet-forked waiting parent takes its
-        children with it; cancelling a single not-yet-forked child just
-        shrinks the group; post-fork, every member is an ordinary
-        independent request and cancels alone.  Unknown or already-
+        groups: cancelling a not-yet-forked parent (waiting or
+        prefilling) takes its children with it; cancelling a single
+        not-yet-forked child just shrinks the group; post-fork, every
+        member is an ordinary independent request and cancels alone.
+        A prefilling row registered nothing in the radix cache, so its
+        blocks just return to the pool.  Unknown or already-
         finished rids are a no-op.  Returns the cancelled request
         (``finish_reason == "cancelled"``) or None."""
         def cancelled(r: Request) -> Request:
@@ -774,14 +858,24 @@ class ContinuousScheduler:
                     if child.rid == rid:
                         req.fork_children.remove(child)
                         return cancelled(child)
-        for slot in self.active_slots:
-            req = self.slots[slot]
-            if req.rid == rid:
-                req.finish_reason = "cancelled"
-                return self._finish(slot, step)
+        for slot, req in enumerate(self.slots):
+            if req is None or req.rid != rid:
+                continue
+            req.finish_reason = "cancelled"
+            parent = self._parent_of(slot)
+            if parent is not None:
+                self.slots[parent].fork_children.remove(req)
+            elif slot in self.prefilling:
+                for cslot in self._seated_children(slot):
+                    child = self.slots[cslot]
+                    self._release_slot(cslot)
+                    cancelled(child)
+                req.fork_children = []
+            return self._finish(slot, step)
         return None
 
     def _release_slot(self, slot: int) -> None:
+        self.prefilling.pop(slot, None)
         self.prefix.release(self.blocks_of.pop(slot))
         req = self.slots[slot]
         req.slot = -1
@@ -793,12 +887,14 @@ class ContinuousScheduler:
     # ------------------------------------------------------------- stats ---
 
     def utilization(self) -> Dict[str, float]:
-        """valid_frac: valid tokens / allocated slots (internal
-        fragmentation); pool_frac: allocated blocks / pool size (cached
-        refcount-0 blocks are counted separately as cached_blocks)."""
+        """valid_frac: valid tokens (decoding rows' lengths, prefilling
+        rows' positions) / allocated slots (internal fragmentation);
+        pool_frac: allocated blocks / pool size (cached refcount-0 blocks
+        are counted separately as cached_blocks)."""
         alloc_blocks = sum(len(v) for v in self.blocks_of.values())
-        valid = int(self.lengths[self.active_slots].sum()) \
-            if self.active_slots else 0
+        active = self.active_slots
+        valid = (int(self.lengths[active].sum()) if active else 0) \
+            + sum(self.prefilling.values())
         return {
             "valid_frac": valid / (alloc_blocks * self.block_size)
             if alloc_blocks else 0.0,

@@ -255,3 +255,43 @@ def test_async_mesh_parity_subprocess():
     for label, (sync, async_) in out.items():
         assert sync == async_, f"{label}: mesh async diverged"
         assert len(sync) == 4
+
+
+# ------------------------------------------------ interleaved prefill ----
+
+
+def _interleaved(engine_cls, cfg, params, **kw):
+    """Long prompts (6-9 chunk steps of 8) arriving while other rows
+    decode, two of them n=2 groups: prefill interleaves with decode."""
+    from repro.runtime import SamplingParams
+    rng = np.random.default_rng(41)
+    specs = [(8, 24, 0, 1), (50, 6, 2, 2), (64, 9, 3, 1), (12, 12, 5, 1),
+             (72, 5, 9, 2), (40, 7, 12, 1)]
+    reqs, rid = [], 0
+    for p, g, a, n in specs:
+        reqs.append(Request(
+            rid=rid, prompt=rng.integers(0, cfg.vocab, (p,)).astype(np.int32),
+            arrival=a, sampling=SamplingParams(max_tokens=g, n=n)))
+        rid += n
+    eng = engine_cls(cfg, params, num_blocks=96, block_size=8, max_batch=4,
+                     max_blocks_per_req=12, compute_dtype=jnp.float32,
+                     scheme="seq", prefill_chunk=8, **kw)
+    eng.run(reqs)
+    assert len(eng.sched.finished) == rid
+    return eng, {r.rid: (tuple(r.output), r.finish_reason)
+                 for r in eng.sched.finished}
+
+
+@pytest.mark.parametrize("kw", [
+    {},                                             # greedy
+    dict(temperature=0.9, top_k=7, sample_seed=5),  # seeded
+], ids=["greedy", "seeded"])
+def test_async_interleaved_prefill_parity(smoke_model, kw):
+    cfg, params = smoke_model
+    es, sync = _interleaved(PagedMLAEngine, cfg, params, **kw)
+    ea, async_ = _interleaved(AsyncPagedMLAEngine, cfg, params, **kw)
+    for eng in (es, ea):
+        assert eng.stats.interleaved_chunks > 0
+        assert eng.summary()["fork_groups"] == 2.0
+        assert eng.prefill_compiles == 1
+    assert sync == async_

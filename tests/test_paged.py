@@ -426,3 +426,199 @@ def test_auto_dispatch_prices_the_attached_tpu_by_device_kind(monkeypatch):
         SimpleNamespace(device_kind="TPU v9")])
     with pytest.raises(KeyError):
         plat.resolve_platform()
+
+
+# ------------------------------------------------ interleaved prefill ----
+
+
+def _interleave_engine(cls, cfg, params, *, max_batch=3, num_blocks=96,
+                       **kw):
+    return cls(cfg, params, num_blocks=num_blocks, block_size=8,
+               max_batch=max_batch, max_blocks_per_req=24,
+               compute_dtype=jnp.float32, scheme="seq", prefill_chunk=16,
+               **kw)
+
+
+def _req(rid, prompt, max_tokens, n=1):
+    from repro.runtime import SamplingParams
+    return Request(rid=rid, prompt=np.asarray(prompt, np.int32),
+                   sampling=SamplingParams(max_tokens=max_tokens, n=n))
+
+
+def _decoding(eng, reqs):
+    """Tick until every request in ``reqs`` decodes."""
+    for r in reqs:
+        eng.submit(r)
+    while not all(r.slot in eng.sched.active_slots for r in reqs):
+        eng.step()
+
+
+@pytest.mark.parametrize("engine", ["sync", "async"])
+def test_prefill_interleaves_one_chunk_per_tick_while_rows_decode(
+        smoke_model, engine):
+    """With rows decoding, a 128-token prompt at prefill_chunk=16 takes 8
+    ticks of one chunk step each, and every running row gains one token
+    per tick meanwhile; the row samples its first token and decodes from
+    the ninth tick on."""
+    from repro.runtime import AsyncPagedMLAEngine
+    cls = {"sync": PagedMLAEngine, "async": AsyncPagedMLAEngine}[engine]
+    cfg, params = smoke_model
+    rng = np.random.default_rng(23)
+    eng = _interleave_engine(cls, cfg, params)
+    running = [_req(i, rng.integers(0, cfg.vocab, 8), 40) for i in range(2)]
+    _decoding(eng, running)
+    eng.step()                                    # steady state (async)
+    long = _req(9, rng.integers(0, cfg.vocab, 128), 4)
+    eng.submit(long)
+    chunks0 = eng.stats.prefill_chunks
+    for tick in range(1, 10):
+        before = [len(r.tokens) for r in running]
+        eng.step()
+        assert [len(r.tokens) for r in running] == [b + 1 for b in before]
+        assert eng.stats.prefill_chunks - chunks0 == min(tick, 8)
+        if tick <= 8:
+            assert eng.sched.prefilling == {long.slot: 16 * tick}
+            assert long.slot not in eng.sched.active_slots
+            assert long.tokens == []
+    assert eng.sched.prefilling == {}
+    assert long.slot in eng.sched.active_slots and long.tokens
+    assert eng.stats.interleaved_chunks == 8
+    assert eng.prefill_compiles == 1
+
+
+def test_prefill_runs_to_its_end_in_one_tick_when_no_row_decodes(
+        smoke_model):
+    cfg, params = smoke_model
+    rng = np.random.default_rng(29)
+    eng = _interleave_engine(PagedMLAEngine, cfg, params)
+    req = _req(0, rng.integers(0, cfg.vocab, 128), 4)
+    eng.submit(req)
+    eng.step()
+    assert eng.stats.prefill_chunks == 8 and eng.stats.steps == 1
+    assert eng.stats.interleaved_chunks == 0
+    assert eng.sched.prefilling == {}
+    assert eng.sched.active_slots == [req.slot]
+    assert len(req.tokens) == 2           # prefill sample + this tick's decode
+
+
+def test_request_admitted_mid_prefill_joins_the_ongoing_chunk_steps(
+        smoke_model):
+    cfg, params = smoke_model
+    rng = np.random.default_rng(31)
+    eng = _interleave_engine(PagedMLAEngine, cfg, params, max_batch=4)
+    running = [_req(0, rng.integers(0, cfg.vocab, 8), 40)]
+    _decoding(eng, running)
+    first = _req(1, rng.integers(0, cfg.vocab, 128), 4)
+    eng.submit(first)
+    for _ in range(3):
+        eng.step()
+    assert eng.sched.prefilling == {first.slot: 48}
+    late = _req(2, rng.integers(0, cfg.vocab, 40), 4)
+    eng.submit(late)
+    chunks0 = eng.stats.prefill_chunks
+    eng.step()
+    # one chunk step served both rows
+    assert eng.stats.prefill_chunks == chunks0 + 1
+    assert eng.sched.prefilling == {first.slot: 64, late.slot: 16}
+    eng.step(), eng.step()
+    assert eng.sched.prefilling == {first.slot: 96, late.slot: 40}
+    eng.step()
+    assert late.slot in eng.sched.active_slots         # 40 tokens: 3 chunks
+    assert eng.sched.prefilling == {first.slot: 112}
+    assert eng.stats.prefill_chunks == chunks0 + 4
+    while eng.sched.prefilling:
+        eng.step()
+    assert eng.stats.prefill_chunks == chunks0 + 5     # 128 = 8 chunks
+    # the interleaved requests read exactly what they read served alone
+    eng.run([])
+    for r in (first, late):
+        alone = _interleave_engine(PagedMLAEngine, cfg, params)
+        alone.run([_req(r.rid, r.prompt, 4)])
+        out, = alone.sched.finished
+        assert r.output == out.output
+
+
+@pytest.mark.parametrize("ticks", [2, 8], ids=["mid-prompt", "prompt-done"])
+def test_cancelled_prefilling_row_frees_blocks_and_caches_nothing(
+        smoke_model, ticks):
+    """Cancelled part-way through its prompt, or after its last chunk and
+    before it sampled its first token (the next tick's work)."""
+    cfg, params = smoke_model
+    rng = np.random.default_rng(37)
+    eng = _interleave_engine(PagedMLAEngine, cfg, params)
+    running = _req(0, rng.integers(0, cfg.vocab, 8), 40)
+    _decoding(eng, [running])
+    long = _req(1, rng.integers(0, cfg.vocab, 128), 4)
+    eng.submit(long)
+    for _ in range(ticks):
+        eng.step()
+    slot = long.slot
+    blocks = list(eng.sched.blocks_of[slot])
+    assert eng.sched.prefilling == {slot: 16 * ticks}
+    eng.request_cancel(long.rid)
+    eng.step()
+    assert long.finish_reason == "cancelled" and long.slot == -1
+    assert long.tokens == []
+    assert eng.sched.prefilling == {} and eng.sched.slots[slot] is None
+    assert all(b not in eng.sched.allocator.refcount for b in blocks)
+    assert eng.sched.prefix.lookup_len(long.prompt) == 0
+    eng.run([])
+    assert running.finish_reason == "length"
+
+
+@pytest.mark.parametrize("n", [1, 2])
+def test_preempted_prefilling_row_frees_blocks_and_caches_nothing(n):
+    """Scheduler level: the pool runs dry while a row (with its seated
+    fork child, for n=2) is part-way through its prompt; the row is the
+    youngest, so it is preempted, releases every block and registers
+    nothing, and is admitted again (children and all) later."""
+    s = ContinuousScheduler(num_blocks=10, block_size=2, max_batch=3)
+    a = _req(0, np.arange(2), 40)
+    s.submit(a)
+    (sa, _), = s.try_admit()
+    s.commit_prefill(sa)
+    s.record_prefill_sample(sa, 1)
+    b = _req(1, np.arange(10, 20), 2, n=n)
+    s.submit(b)
+    (sb, _), = s.try_admit()
+    held = [sb] + [c.slot for c in b.fork_children]
+    held_blocks = [blk for sl in held for blk in s.blocks_of[sl]]
+    assert s.active_slots == [sa] and set(s._held()) == set(held)
+    tokens, lens, nv, finishing = s.next_chunk(4)
+    assert nv[sb] == 4 and finishing == [] and s.prefilling == {sb: 4}
+    # a decodes until its growth finds the pool dry
+    for _ in range(20):
+        pre = s.ensure_step_capacity()
+        if pre:
+            break
+        s.advance({sa: 1})
+    assert pre == [b] and s.waiting[0] is b and b.tokens == []
+    assert s.prefilling == {} and all(s.slots[sl] is None for sl in held)
+    # every block b's group held is free again or grew a
+    assert set(held_blocks) <= s.allocator._free_set | set(s.blocks_of[sa])
+    assert s.prefix.lookup_len(b.prompt) == 0
+    assert s.active_slots == [sa]
+    assert all(c.slot == -1 for c in b.fork_children)
+    s.cancel(a.rid)
+    (sb2, again), = s.try_admit()
+    assert again is b and s.prefilling == {sb2: 0}
+    assert all(c.slot >= 0 for c in b.fork_children)
+
+
+def test_decode_view_hides_rows_that_do_not_decode_yet():
+    s = ContinuousScheduler(num_blocks=16, block_size=2, max_batch=3)
+    s.submit(_req(0, np.arange(3), 4))
+    (sa, _), = s.try_admit()
+    s.commit_prefill(sa)
+    s.submit(_req(1, np.arange(5), 4, n=2))
+    (sb, b), = s.try_admit()
+    sc = b.fork_children[0].slot
+    table, lengths = s.decode_view()
+    assert (table[[sb, sc]] == NULL_BLOCK).all() and (lengths[[sb, sc]] == 0).all()
+    assert (table[sa] == s.block_table[sa]).all() and lengths[sa] == 3
+    assert s.block_table[sb, 0] != NULL_BLOCK and s.lengths[sb] == 5
+    s.commit_prefill(sb)
+    s.fork_group(sb)
+    assert s.active_slots == [sa, sb, sc]
+    table, lengths = s.decode_view()
+    assert table is s.block_table and lengths is s.lengths
